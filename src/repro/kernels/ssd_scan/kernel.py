@@ -32,19 +32,29 @@ def _ssd_kernel(xdt_ref, loga_ref, b_ref, c_ref, y_ref, h_scr, *,
         h_scr[...] = jnp.zeros_like(h_scr)
 
     xdt = xdt_ref[0].astype(jnp.float32)        # [Q, P]
-    loga = loga_ref[0].astype(jnp.float32)      # [Q]
+    loga = loga_ref[0].astype(jnp.float32)      # [1, Q]
     B = b_ref[0].astype(jnp.float32)            # [Q, N]
     C = c_ref[0].astype(jnp.float32)            # [Q, N]
     Q = xdt.shape[0]
 
-    cums = jnp.cumsum(loga)                     # [Q]
+    # inclusive prefix sums of the log-decay as one matmul with the
+    # causal ones mask (Mosaic has no cumsum), in row and column form
+    ii = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    causal = ii >= jj
+    tri = causal.astype(jnp.float32)
+    exact = jax.lax.Precision.HIGHEST
+    cum_col = jax.lax.dot_general(tri, loga, (((1,), (1,)), ((), ())),
+                                  precision=exact,
+                                  preferred_element_type=jnp.float32)  # [Q, 1]
+    cum_row = jax.lax.dot_general(loga, tri, (((1,), (1,)), ((), ())),
+                                  precision=exact,
+                                  preferred_element_type=jnp.float32)  # [1, Q]
+    total = jnp.sum(loga)
     # intra-chunk quadratic form
     G = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # [Q, Q]
-    rel = cums[:, None] - cums[None, :]
-    ii = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    L = jnp.where(ii >= jj, jnp.exp(rel), 0.0)
+    L = jnp.where(causal, jnp.exp(cum_col - cum_row), 0.0)
     y_intra = jax.lax.dot_general(G * L, xdt, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
 
@@ -52,15 +62,14 @@ def _ssd_kernel(xdt_ref, loga_ref, b_ref, c_ref, y_ref, h_scr, *,
     h = h_scr[...]
     y_inter = jax.lax.dot_general(C, h, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-    y_inter = y_inter * jnp.exp(cums)[:, None]
+    y_inter = y_inter * jnp.exp(cum_col)
     y_ref[0] = (y_intra + y_inter).astype(y_ref.dtype)
 
     # state update
-    decay_out = jnp.exp(cums[-1] - cums)                    # [Q]
-    xb = xdt * decay_out[:, None]                           # [Q, P]
+    xb = xdt * jnp.exp(total - cum_col)                     # [Q, P]
     dh = jax.lax.dot_general(xb, B, (((0,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)  # [P, N]
-    h_scr[...] = h * jnp.exp(cums[-1]) + dh
+    h_scr[...] = h * jnp.exp(total) + dh
 
 
 def ssd_scan(xdt, loga, B, C, *, interpret: bool = False):
@@ -73,7 +82,9 @@ def ssd_scan(xdt, loga, B, C, *, interpret: bool = False):
     nc = S // Q
 
     xf = xdt.reshape(Bz * H, S, P)
-    lf = loga.reshape(Bz * H, S)
+    # a unit middle axis makes the (1, Q) block trailing dims tiling-legal
+    # (1 = the whole axis, Q a multiple of 128 or the whole sequence)
+    lf = loga.reshape(Bz * H, 1, S)
     # broadcast B/C across heads to keep the index maps affine
     bf = jnp.repeat(B, H, axis=0).reshape(Bz * H, S, N)
     cf = jnp.repeat(C, H, axis=0).reshape(Bz * H, S, N)
@@ -84,7 +95,7 @@ def ssd_scan(xdt, loga, B, C, *, interpret: bool = False):
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, Q, P), lambda bh, c: (bh, c, 0)),
-            pl.BlockSpec((1, Q), lambda bh, c: (bh, c)),
+            pl.BlockSpec((1, 1, Q), lambda bh, c: (bh, 0, c)),
             pl.BlockSpec((1, Q, N), lambda bh, c: (bh, c, 0)),
             pl.BlockSpec((1, Q, N), lambda bh, c: (bh, c, 0)),
         ],

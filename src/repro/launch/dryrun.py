@@ -5,6 +5,9 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
+A CPU-only tool: it forces 512 virtual host devices before JAX starts
+and compiles for them, so it never runs on (or needs) an accelerator.
+
 For each cell this produces:
   * proof of compilation on the production mesh (256-chip single pod and
     512-chip two-pod);
@@ -38,8 +41,6 @@ from ..train.step import make_train_step
 
 def _analysis(lowered, compiled, mesh, extra):
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):    # jax 0.4.x: one dict per program
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     chips = mesh.devices.size
     roof = hlo_analysis.analyze(compiled.as_text(), chips)

@@ -133,21 +133,20 @@ def pallas_attention(cfg, p, x, positions, *, causal: bool = True,
     """Forward attention through the Pallas flash kernel (VMEM-tiled).
 
     On TPU this compiles to a Mosaic kernel; in the CPU dry-run the
-    interpret-mode lowering produces the same *traffic shape* (per-tile
-    loads inside the grid loop instead of S×T score materialization),
-    which is what the roofline memory term measures.  Forward-only:
-    training wraps it in jax.checkpoint so the backward recomputes via
-    the chunked path.
+    interpret-mode lowering (``runtime.pallas_interpret``) produces the
+    same *traffic shape* (per-tile loads inside the grid loop instead of
+    S×T score materialization), which is what the roofline memory term
+    measures.  Forward-only: training wraps it in jax.checkpoint so the
+    backward recomputes via the chunked path.
     """
     from ..kernels.flash_attention.kernel import flash_attention
-    import jax as _jax
+    from ..runtime import pallas_interpret
     B, S, _ = x.shape
     h, kvh, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q, k, v = _qkv(cfg, p, x, positions)
-    interpret = _jax.default_backend() != "tpu"
     out = flash_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                           v.transpose(0, 2, 1, 3), causal=causal,
-                          window=window, interpret=interpret)
+                          window=window, interpret=pallas_interpret())
     out = out.transpose(0, 2, 1, 3).reshape(B, S, h * dh)
     return jnp.einsum("bse,ed->bsd", out, p["wo"]), (k, v)
 

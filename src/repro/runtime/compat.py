@@ -1,114 +1,65 @@
-"""Version-agnostic jax device/mesh/sharding layer.
+"""The jax device/mesh/sharding layer.
 
 Every other module builds meshes and shard_maps through *this* file, so
-one place absorbs the churn in jax's public surface instead of every
-call site pinning a version:
+one place holds the spelling of jax's public surface instead of every
+call site:
 
-  * ``jax.sharding.AxisType`` + the ``axis_types=`` kwarg of
-    ``jax.make_mesh`` exist only in newer jax; 0.4.x meshes have no axis
-    types at all (everything behaves like ``Auto``).
-  * ``jax.shard_map`` (with ``check_vma=``) is the new spelling of
-    ``jax.experimental.shard_map.shard_map`` (with ``check_rep=``).
-  * very old jax lacks ``jax.make_mesh`` entirely; we fall back to
-    reshaping ``jax.devices()`` into a ``jax.sharding.Mesh`` by hand.
+  * meshes carry ``jax.sharding.AxisType.Auto`` on every axis;
+  * ``shard_map`` is ``jax.shard_map`` with its ``check_vma=`` flag;
+  * Pallas kernels run compiled on a TPU and interpreted elsewhere —
+    ``pallas_interpret`` is the one place that decides;
+  * ``enable_compile_cache`` places JAX's persistent compilation cache.
 
-All detection is import-time ``hasattr``/try-import — no version string
-parsing, so prerelease/vendored builds resolve to whatever they actually
-provide.  The application-facing API is deliberately tiny (the Puddles
-argument: recovery/runtime layers should be application independent):
+The application-facing API is deliberately tiny (the Puddles argument:
+recovery/runtime layers should be application independent):
 
   ``make_mesh``, ``make_host_mesh``, ``axis_types_kwargs``,
-  ``shard_map``, ``named_sharding``, ``AXIS_TYPE_AUTO``.
+  ``shard_map``, ``named_sharding``, ``AXIS_TYPE_AUTO``, ``axis_size``,
+  ``pallas_interpret``, ``enable_compile_cache``.
 """
 
 from __future__ import annotations
 
-import inspect
-import math
-from typing import Any, Sequence
-
-import numpy as np
+import os
+import pathlib
+from typing import Sequence
 
 import jax
 from jax.sharding import Mesh, NamedSharding
 
-# --------------------------------------------------------------- detection
-# jax.sharding.AxisType arrives via a module __getattr__ that *raises* on
-# old versions, so getattr with a default is the whole feature probe.
-AXIS_TYPE_AUTO: Any = None
-_axis_type_cls = getattr(jax.sharding, "AxisType", None)
-if _axis_type_cls is not None:
-    AXIS_TYPE_AUTO = _axis_type_cls.Auto
+AXIS_TYPE_AUTO = jax.sharding.AxisType.Auto
 
-_HAS_AXIS_TYPES = AXIS_TYPE_AUTO is not None
+axis_size = jax.lax.axis_size
 
-_make_mesh = getattr(jax, "make_mesh", None)
-_MAKE_MESH_TAKES_AXIS_TYPES = (
-    _make_mesh is not None
-    and "axis_types" in inspect.signature(_make_mesh).parameters)
-
-try:                                        # new spelling (jax >= 0.6)
-    _shard_map = jax.shard_map
-    _SHARD_MAP_REP_KWARG = "check_vma"
-except AttributeError:                      # 0.4.x/0.5.x spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_REP_KWARG = "check_rep"
+# <repo>/src/repro/runtime/compat.py -> <repo>
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
 
 
 # ------------------------------------------------------------------- mesh
 def axis_types_kwargs(n_axes: int) -> dict:
-    """``{"axis_types": (Auto,) * n}`` where supported, else ``{}``.
-
-    On jax 0.4.x meshes carry no axis types and the auto-SPMD partitioner
-    treats every axis as ``Auto`` — dropping the kwarg is semantically
-    the identity, not an approximation.
-    """
-    if _HAS_AXIS_TYPES and _MAKE_MESH_TAKES_AXIS_TYPES:
-        return {"axis_types": (AXIS_TYPE_AUTO,) * n_axes}
-    return {}
+    """``{"axis_types": (Auto,) * n}`` for ``jax.make_mesh``."""
+    return {"axis_types": (AXIS_TYPE_AUTO,) * n_axes}
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               *, devices=None) -> Mesh:
-    """Build a ``Mesh`` with Auto axis types on any jax version."""
-    axis_shapes = tuple(axis_shapes)
-    axis_names = tuple(axis_names)
-    if _make_mesh is not None:
-        kw = axis_types_kwargs(len(axis_names))
-        if devices is not None:
-            kw["devices"] = devices
-        return _make_mesh(axis_shapes, axis_names, **kw)
-    # pre-make_mesh fallback: reshape the flat device list ourselves
-    devs = list(jax.devices()) if devices is None else list(devices)
-    need = math.prod(axis_shapes)
-    if len(devs) < need:
-        raise ValueError(
-            f"mesh {axis_shapes} needs {need} devices, have {len(devs)}")
-    grid = np.array(devs[:need]).reshape(axis_shapes)
-    return Mesh(grid, axis_names)
+    """Build a ``Mesh`` with Auto axis types."""
+    kw = axis_types_kwargs(len(axis_names))
+    if devices is not None:
+        kw["devices"] = devices
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
-    """The (data, model) mesh every CPU test/example uses."""
+    """The (data, model) mesh the serving engine runs on."""
     return make_mesh((data, model), ("data", "model"))
 
 
 # -------------------------------------------------------------- shard_map
-if hasattr(jax.lax, "axis_size"):
-    axis_size = jax.lax.axis_size
-else:
-    def axis_size(axis_name):
-        """Static mapped-axis size; ``psum`` of a scalar literal is
-        constant-folded to a Python int, the pre-``lax.axis_size`` idiom."""
-        return jax.lax.psum(1, axis_name)
-
-
 def shard_map(f, *, mesh, in_specs, out_specs, check_replication: bool = False):
-    """Portable ``shard_map``: one boolean replication-check knob mapped to
-    whichever of ``check_vma``/``check_rep`` this jax spells it as."""
-    kw = {_SHARD_MAP_REP_KWARG: check_replication}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
+    """``jax.shard_map`` with one boolean replication-check knob."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_replication)
 
 
 # ------------------------------------------------------------- shardings
@@ -116,3 +67,28 @@ def named_sharding(mesh: Mesh, spec) -> NamedSharding:
     """``NamedSharding`` constructor (single choke point should the class
     move again, as ``MeshPspecSharding`` → ``NamedSharding`` once did)."""
     return NamedSharding(mesh, spec)
+
+
+# ---------------------------------------------------------------- pallas
+def pallas_interpret() -> bool:
+    """Whether a Pallas kernel called without an explicit ``interpret=``
+    runs in the interpreter: everywhere but on a TPU."""
+    return jax.devices()[0].platform != "tpu"
+
+
+# --------------------------------------------------------- compile cache
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its path.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing else is set; otherwise the cache lives at the fixed
+    ``<repo>/.jax_cache`` (the path is part of each entry's key, so it
+    must not move between runs).  Every compiled program is kept, small
+    allocator programs included.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path

@@ -50,7 +50,8 @@ from .prefix_store import PrefixStore
 from .prefix_trie_cache import CacheNode, PrefixTrieCache
 from .scheduler import EngineBusy, PendingPublish
 
-__all__ = ["ServingEngine", "Session", "EngineBusy", "PAGE_CLS"]
+__all__ = ["ServingEngine", "Session", "EngineBusy", "PAGE_CLS",
+           "arena_config"]
 
 PAGE_CLS = 0
 
@@ -69,6 +70,22 @@ _OBS_PUB_DEPTH = obs.gauge("engine.publish_queue_depth")
 _OBS_PUB_BATCH = obs.histogram("engine.publish_batch_size")
 
 
+def arena_config(cfg: ModelConfig, lanes: int, max_seq: int,
+                 pages_per_sb: int = 16) -> ja.ArenaConfig:
+    """The KV arena an engine of this shape allocates from (1 block =
+    1 page; the decode state holds ``num_sbs * sb_words + 1`` pages, the
+    last one the dump page).
+
+    A whole number of superblocks per lane, so that a decode-ahead span
+    (max_seq pages rounded UP to superblocks by alloc_large) always fits
+    for every lane at once — per-page slack alone would under-provision
+    the superblock rounding."""
+    per_lane_sbs = -(-(max_seq // cfg.page_size + 2) // pages_per_sb)
+    return ja.ArenaConfig(num_sbs=lanes * per_lane_sbs + 1,
+                          sb_words=pages_per_sb, class_words=(1,),
+                          cache_cap=max(64, 2 * lanes))
+
+
 class ServingEngine:
     def __init__(self, cfg: ModelConfig, mesh, params, *, lanes: int = 8,
                  max_seq: int = 512, pages_per_sb: int = 16,
@@ -78,15 +95,7 @@ class ServingEngine:
         self.params = params
         self.lanes = lanes
         self.max_seq = max_seq
-        # arena sizing: a whole number of superblocks per lane, so that a
-        # decode-ahead span (max_seq pages rounded UP to superblocks by
-        # alloc_large) always fits for every lane at once — per-page slack
-        # alone would under-provision the superblock rounding
-        per_lane_sbs = -(-(max_seq // cfg.page_size + 2) // pages_per_sb)
-        num_sbs = lanes * per_lane_sbs + 1
-        self.acfg = ja.ArenaConfig(num_sbs=num_sbs, sb_words=pages_per_sb,
-                                   class_words=(1,),
-                                   cache_cap=max(64, 2 * lanes))
+        self.acfg = arena_config(cfg, lanes, max_seq, pages_per_sb)
         # root slots: one per lane (page tables) + one per hash bucket of
         # the durable prefix index's record chains (serving.prefix_store) —
         # bucket b's chain head mirrors into roots[lanes + b]
@@ -111,8 +120,8 @@ class ServingEngine:
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)
         self.step_fn, _, _ = dec.make_decode_step(cfg, mesh, pshape)
         self.dstate = dec.make_dstate(cfg, batch=lanes, max_seq=max_seq,
-                                      pages_per_shard=int(num_sbs
-                                                          * pages_per_sb) + 1)
+                                      pages_per_shard=self.acfg.total_words
+                                      + 1)
         # prefix sharing (RadixAttention-style) — the trie cache keeps
         # the flat exact-match dict API (entries / tokens / page_refs /
         # lookup) and adds longest-prefix-match over published prompts:
@@ -759,6 +768,44 @@ class ServingEngine:
             self.dstate["block_table"].at[lane].set(-1)
         self.astate = ja.set_root(self.astate, lane, jnp.int32(-1))
         self.lane_states.release(lane)
+
+    def check_occupancy(self) -> dict:
+        """The allocator's live blocks must be exactly the pages the live
+        lanes hold: every page-class block is one lane's lazily-allocated
+        page (held by no other lane), and every large span is one lane's
+        reservation with its superblocks placed.  Valid only while no
+        prefix is shared.  Raises on a mismatch; returns the counts."""
+        if self.prefix_cache.entries or self.shared_spans:
+            raise ValueError("check_occupancy assumes no prefix sharing")
+        bt = np.asarray(self.dstate["block_table"])
+        lazy: list[int] = []
+        span_sbs = 0
+        for lane in self.sessions:
+            pages = bt[lane][bt[lane] >= 0]
+            span = self.large_spans.get(lane)
+            if span is not None:
+                off, n = span
+                if pages[:n].tolist() != list(range(off, off + n)):
+                    raise AssertionError(f"lane {lane}: span pages "
+                                         f"{pages[:n]} != [{off}, {off + n})")
+                pages = pages[n:]
+                span_sbs += -(-n // self.acfg.sb_words)
+            lazy.extend(pages.tolist())
+        if len(set(lazy)) != len(lazy):
+            raise AssertionError(f"a lazy page is held by two lanes: {lazy}")
+        live = ja.live_blocks(self.astate, self.acfg)
+        sb_class = np.asarray(self.astate.sb_class)
+        placed = int(np.isin(sb_class, (ja.LARGE_CLS, ja.LARGE_CONT)).sum())
+        occ = {"live_pages": live[PAGE_CLS], "lane_pages": len(lazy),
+               "live_spans": live["large"],
+               "lane_spans": len(self.large_spans),
+               "span_superblocks": placed, "lane_span_superblocks": span_sbs}
+        if (occ["live_pages"] != occ["lane_pages"]
+                or occ["live_spans"] != occ["lane_spans"]
+                or placed != span_sbs):
+            raise AssertionError(f"allocator occupancy != lane holdings: "
+                                 f"{occ}")
+        return occ
 
     # ------------------------------------------------------------- recovery
     def ref_table(self) -> np.ndarray:
