@@ -21,6 +21,10 @@ Metric naming conventions (see ROADMAP "Observability"):
   ``trie.*``      prefix-cache hit depth distribution
   ``recovery.*``  named recovery phases (span timings)
   ``persist.*``   waste gauges from an attached :class:`WasteMonitor`
+
+``set_annotator(fn)`` has every span also enter ``fn(name)`` while the
+registry is enabled: ``repro.serving`` puts the spans on the JAX
+profiler's trace this way (``jax.profiler.TraceAnnotation``).
 """
 
 from __future__ import annotations
@@ -32,8 +36,8 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "UnknownMetric",
     "WasteMonitor", "get_registry", "counter", "gauge", "gauge_fn",
     "histogram", "register_source", "span", "snapshot", "chrome_trace",
-    "reset", "reset_all", "enable", "disable", "is_enabled",
-    "attach_waste_monitor",
+    "span_events", "set_annotator", "reset", "reset_all", "enable",
+    "disable", "is_enabled", "attach_waste_monitor",
 ]
 
 _default = Registry(enabled=True)
@@ -73,6 +77,16 @@ def snapshot() -> dict:
 
 def chrome_trace() -> dict:
     return _default.chrome_trace()
+
+
+def span_events(since: float = float("-inf")):
+    return _default.span_events(since)
+
+
+def set_annotator(fn) -> None:
+    """Enter ``fn(name)`` (a context manager) around every span of the
+    default registry while it is enabled; ``None`` removes it."""
+    _default.annotator = fn
 
 
 def reset(*names: str) -> None:

@@ -23,10 +23,16 @@ Design constraints (ISSUE 10):
 
 Spans (:meth:`Registry.span`) always *time* — recovery stats carry their
 phase durations whether or not metrics are on — but only *record* (trace
-event + accumulated phase row) while the registry is enabled.  Exported
-trace events follow the Chrome ``traceEvents`` format (``ph: "X"``,
-microsecond ``ts``/``dur``), loadable in ``chrome://tracing`` and
-Perfetto.
+event + accumulated phase row) while the registry is enabled.  The span
+buffer keeps the newest ``TRACE_CAP`` events; exported trace events
+follow the Chrome ``traceEvents`` format (``ph: "X"``, microsecond
+``ts``/``dur``), loadable in ``chrome://tracing`` and Perfetto.
+
+An optional *annotator* (``Registry.annotator``: a callable
+``name -> context manager``) is entered around every span while the
+registry is enabled, so a profiler can show the same spans on its own
+clock; ``repro.serving`` installs ``jax.profiler.TraceAnnotation``.
+This module never imports it.
 
 Counters tolerate racy ``+=`` under the GIL (a lost increment is an
 observability blip, not corruption); structural mutation of the registry
@@ -35,6 +41,7 @@ itself is lock-protected.
 
 from __future__ import annotations
 
+import collections
 import threading
 import time
 
@@ -137,12 +144,14 @@ class Histogram:
 
 class _Span:
     """Context manager timing one named phase.  Always times (callers
-    read ``.seconds`` for their own stats); records a Chrome-trace event
-    and accumulates into the registry's phase table only while enabled.
-    ``add(n)`` attributes an item count to the phase (blocks swept,
-    records pruned, ...)."""
+    read ``.seconds`` for their own stats); records a trace event,
+    accumulates into the registry's phase table and enters the
+    registry's annotator only if the registry is enabled when the span
+    opens.  ``add(n)`` attributes an item count to the phase (blocks
+    swept, records pruned, ...); ``args`` may be filled in before the
+    span closes."""
 
-    __slots__ = ("name", "args", "seconds", "items", "_reg", "_t0")
+    __slots__ = ("name", "args", "seconds", "items", "_reg", "_t0", "_ann")
 
     def __init__(self, reg: "Registry", name: str, args: dict):
         self._reg = reg
@@ -150,19 +159,29 @@ class _Span:
         self.args = args
         self.seconds = 0.0
         self.items = 0
+        self._ann = None
 
     def add(self, n: int = 1) -> None:
         self.items += n
 
     def __enter__(self) -> "_Span":
+        reg = self._reg
+        if reg.enabled:
+            ann = reg.annotator
+            if ann is not None:
+                ann = ann(self.name)
+                ann.__enter__()
+            self._ann = ann if ann is not None else False
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.seconds = time.perf_counter() - self._t0
-        reg = self._reg
-        if reg.enabled:
-            reg._record_span(self)
+        ann = self._ann
+        if ann is not None:
+            if ann is not False:
+                ann.__exit__(exc_type, exc, tb)
+            self._reg._record_span(self)
 
 
 class Registry:
@@ -172,19 +191,23 @@ class Registry:
     identity per name, so modules can cache the object at import time).
     """
 
-    TRACE_CAP = 20000
+    TRACE_CAP = 1 << 15
 
     def __init__(self, enabled: bool = True):
         self.enabled = enabled
+        self.annotator = None      # name -> context manager, or None
         self._lock = threading.Lock()
         self._counters: dict[str, Counter] = {}
         self._gauges: dict[str, Gauge] = {}
         self._histograms: dict[str, Histogram] = {}
         self._sources: dict[str, tuple] = {}     # name -> (read, reset|None)
         self._phases: dict[str, dict] = {}
-        self._trace: list[dict] = []
+        # (name, start perf_counter, seconds, args, items, thread id)
+        self._trace: collections.deque = collections.deque(
+            maxlen=self.TRACE_CAP)
         self._trace_epoch = time.perf_counter()
         self._trace_dropped = 0
+        self._dropped_t0 = float("-inf")    # latest start let go of
 
     # ------------------------------------------------------------ lifecycle
     def enable(self) -> None:
@@ -241,17 +264,12 @@ class Registry:
         row["seconds"] += span.seconds
         row["items"] += span.items
         row["calls"] += 1
-        if len(self._trace) >= self.TRACE_CAP:
+        if len(self._trace) == self.TRACE_CAP:
+            # the first event to have closed makes room
             self._trace_dropped += 1
-            return
-        ev = {"name": span.name, "ph": "X", "pid": 0,
-              "tid": threading.get_ident() & 0xFFFF,
-              "ts": round((span._t0 - self._trace_epoch) * 1e6, 3),
-              "dur": round(span.seconds * 1e6, 3)}
-        if span.args or span.items:
-            ev["args"] = dict(span.args, items=span.items) \
-                if span.items else dict(span.args)
-        self._trace.append(ev)
+            self._dropped_t0 = max(self._dropped_t0, self._trace[0][1])
+        self._trace.append((span.name, span._t0, span.seconds, span.args,
+                            span.items, threading.get_ident()))
 
     # -------------------------------------------------------------- queries
     def snapshot(self) -> dict:
@@ -269,10 +287,29 @@ class Registry:
 
     def chrome_trace(self) -> dict:
         """The span buffer in Chrome ``traceEvents`` format (loadable in
-        chrome://tracing / Perfetto)."""
-        return {"traceEvents": list(self._trace),
-                "displayTimeUnit": "ms",
+        chrome://tracing / Perfetto); ``dropped`` counts the oldest
+        events the buffer let go."""
+        events = []
+        for name, t0, seconds, args, items, tid in list(self._trace):
+            ev = {"name": name, "ph": "X", "pid": 0, "tid": tid & 0xFFFF,
+                  "ts": round((t0 - self._trace_epoch) * 1e6, 3),
+                  "dur": round(seconds * 1e6, 3)}
+            if args or items:
+                ev["args"] = dict(args, items=items) if items else dict(args)
+            events.append(ev)
+        return {"traceEvents": events, "displayTimeUnit": "ms",
                 "otherData": {"dropped": self._trace_dropped}}
+
+    def span_events(self, since: float = float("-inf")) -> list | None:
+        """The buffered spans that opened at or after ``since``, in the
+        order they closed, as ``(name, start, end, args)`` on the
+        ``time.perf_counter`` clock; ``None`` if the buffer has let go
+        of one of them."""
+        if self._trace_dropped and self._dropped_t0 >= since:
+            return None
+        return [(name, t0, t0 + seconds, args)
+                for name, t0, seconds, args, _items, _tid in list(self._trace)
+                if t0 >= since]
 
     # --------------------------------------------------------------- resets
     def reset(self, *names: str) -> None:
@@ -309,4 +346,5 @@ class Registry:
         self._phases.clear()
         self._trace.clear()
         self._trace_dropped = 0
+        self._dropped_t0 = float("-inf")
         self._trace_epoch = time.perf_counter()

@@ -2,7 +2,9 @@
 
 One decode step = embed → scan over pattern units (each unit applies its
 mixers/ffns via ``tp_layers``) → final norm → vocab-parallel logits →
-greedy sample.  Batch and KV pages are sharded over the data axes
+greedy sample.  Named scopes (``attention``, with ``kv_write`` and
+``kv_gather`` inside it, ``ffn``, ``logits``) label the compiled ops in
+a profile.  Batch and KV pages are sharded over the data axes
 (shard-local page ids — one arena per data shard); ``model`` carries
 Megatron-style TP plus slot-sharded paged attention.
 
@@ -228,10 +230,11 @@ def _apply_layer_tp(cfg, spec, p, x, pos, block_table, kv_pos, state,
         win = cfg.window if mixer == "local_attn" else 0
         scales = ((state["ks"], state["vs"])
                   if cfg.kv_dtype == "int8" else None)
-        y, ak, av, kv_pos2, nsc = tpl.attn_decode_tp(
-            cfg, p["attn"], h, pos, state["k"], state["v"], block_table,
-            kv_pos, window=win, axis=M, seq_dp_axes=seq_dp_axes,
-            scales=scales)
+        with jax.named_scope("attention"):
+            y, ak, av, kv_pos2, nsc = tpl.attn_decode_tp(
+                cfg, p["attn"], h, pos, state["k"], state["v"], block_table,
+                kv_pos, window=win, axis=M, seq_dp_axes=seq_dp_axes,
+                scales=scales)
         new_state = {"k": ak, "v": av}
         if nsc is not None:
             new_state["ks"], new_state["vs"] = nsc
@@ -242,10 +245,11 @@ def _apply_layer_tp(cfg, spec, p, x, pos, block_table, kv_pos, state,
     x = x + y
     if ffn != "none":
         h = apply_norm(cfg.norm, p["norm2"], x)
-        if ffn == "moe":
-            x = x + tpl.moe_decode_tp(cfg, p["ffn"], h, M)
-        else:
-            x = x + tpl.mlp_decode_tp(cfg, p["ffn"], h, M)
+        with jax.named_scope("ffn"):
+            if ffn == "moe":
+                x = x + tpl.moe_decode_tp(cfg, p["ffn"], h, M)
+            else:
+                x = x + tpl.mlp_decode_tp(cfg, p["ffn"], h, M)
     return x, new_state
 
 
@@ -279,8 +283,10 @@ def _decode_local(cfg: ModelConfig, seq_dp_axes, params, dstate, tokens,
             new_tail[f"t{i}"] = ns
     x = apply_norm(cfg.norm, params["final_norm"], x)
     table = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    logits_loc = tpl.logits_tp(table, x, M)
-    next_tok = tpl.greedy_sample_tp(logits_loc, M, sharded=vocab_sharded)
+    with jax.named_scope("logits"):
+        logits_loc = tpl.logits_tp(table, x, M)
+        next_tok = tpl.greedy_sample_tp(logits_loc, M,
+                                        sharded=vocab_sharded)
 
     # one new token is now resident at position pos for every sequence:
     # advance position; mark its slot in kv_pos (idempotent w.r.t. layers)

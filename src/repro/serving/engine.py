@@ -56,18 +56,40 @@ __all__ = ["ServingEngine", "Session", "EngineBusy", "PAGE_CLS",
 PAGE_CLS = 0
 
 # Engine metrics (cached at import; see repro.obs conventions).
-# ``device.*`` counts invocations of the jit-compiled allocator wrappers
-# (the device-side fast path is inside the trace and unobservable from
-# the host — the host FreeRunIndex carries the per-bucket placement
-# metrics); ``engine.publish_*`` tracks the group-commit queue.
-_OBS_DEV_ALLOC = obs.counter("device.alloc_calls")
-_OBS_DEV_ALLOC_LARGE = obs.counter("device.alloc_large_calls")
-_OBS_DEV_TRIM = obs.counter("device.trim_calls")
+# ``engine.publish_*`` tracks the group-commit queue.
 _OBS_SPAN_RESERVE_FAIL = obs.counter("device.span_reserve_failed")
 _OBS_PUB_QUEUED = obs.counter("engine.publish_queued")
 _OBS_PUB_FLUSHES = obs.counter("engine.publish_flushes")
 _OBS_PUB_DEPTH = obs.gauge("engine.publish_queue_depth")
 _OBS_PUB_BATCH = obs.histogram("engine.publish_batch_size")
+
+# Span names: the host-side phases of a step and of a request's life
+_SP_STEP = "engine.step"
+_SP_PAGE_ALLOC = "engine.page_alloc"   # lane pages: alloc + table upload
+_SP_DISPATCH = "engine.dispatch"       # tokens upload + the step program
+_SP_EMIT = "engine.emit"               # per-lane loop after the tokens
+_SP_FINISH = "engine.finish"
+_SP_ADD = "engine.add_request"
+# ... and of each device->host read (``_d2h``)
+_D2H_POS = "engine.d2h.pos"
+_D2H_TOKENS = "engine.d2h.tokens"
+_D2H_BT = "engine.d2h.block_table"
+_D2H_BT_ROW = "engine.d2h.bt_row"
+_D2H_KV_POS = "engine.d2h.kv_pos"
+_D2H_OFFS = "engine.d2h.offs"
+_D2H_SPAN_OFF = "engine.d2h.span_off"
+_D2H_MARKED = "engine.d2h.marked"
+_D2H_LIVE = "engine.d2h.live_records"
+
+# the spans also land on the JAX profiler's trace, on its host clock
+obs.set_annotator(jax.profiler.TraceAnnotation)
+
+
+def _d2h(name: str, x) -> np.ndarray:
+    """Read a device array to the host: the one way the served path
+    does, so that each read shows as span ``name``."""
+    with obs.span(name):
+        return np.asarray(x)
 
 
 def arena_config(cfg: ModelConfig, lanes: int, max_seq: int,
@@ -185,136 +207,140 @@ class ServingEngine:
     # ------------------------------------------------------------- requests
     def add_request(self, prompt: list[int],
                     share_prefix: bool = False) -> int:
-        lane = self.lane_states.acquire()
-        if lane is None:
-            raise EngineBusy(
-                f"all {self.lanes} lanes are busy — queue admission through "
-                f"serving.scheduler.Scheduler.submit")
-        self.sessions[lane] = Session(lane=lane, tokens=list(prompt))
-        # reset lane state (pos=0) and feed the prompt token by token
-        self.dstate = reset_lane(self.dstate, lane)
-        self.cur_tokens[lane] = prompt[0]
-        # oversized prompt: its page table will not fit the per-step lazy
-        # path gracefully — reserve one contiguous multi-superblock span up
-        # front (device large-object path) sized *decode-ahead*: the span
-        # covers every page the sequence can ever touch (max_seq, not just
-        # the prompt), so generation never needs a mid-decode lazy page or
-        # a span migration.  Clamped to the page-table width: generation
-        # stops at max_seq, so pages past it would never be touched.
-        # A shared-prefix *hit* on a published span skips the reservation
-        # entirely: the lane acquires the published span instead.
-        table_width = int(self.dstate["block_table"].shape[1])
-        n_prompt_pages = min(-(-len(prompt) // self.cfg.page_size),
-                             table_width)
-        hit = self.prefix_cache.lookup(prompt) if share_prefix else None
-        # longest-prefix match when the exact entry misses: a request
-        # matching k whole pages of a published prompt leases only those
-        # k pages' superblocks and decodes its suffix on its own lazily-
-        # allocated pages.  A mid-edge match first materializes the
-        # boundary as a trie split (durable when the node has a record).
-        pnode, pk = None, 0
-        if share_prefix and hit is None and self.cfg.attn_layers > 0:
-            pnode, pk = self.prefix_cache.match_partial(prompt)
-            if pnode is not None and pk * self.cfg.page_size >= len(prompt):
-                pnode, pk = None, 0    # no suffix left: only the exact
-                #                        entry may serve the whole prompt
-            elif pnode is not None and pk < pnode.end_page:
-                m = self._split_node(pnode, pk)
-                if m is None:          # no record blocks: fall back to
-                    #                    the deepest existing boundary
-                    pnode, pk = self.prefix_cache.deepest_boundary(pnode, pk)
+        with obs.span(_SP_ADD):
+            lane = self.lane_states.acquire()
+            if lane is None:
+                raise EngineBusy(
+                    f"all {self.lanes} lanes are busy — queue admission "
+                    f"through serving.scheduler.Scheduler.submit")
+            self.sessions[lane] = Session(lane=lane, tokens=list(prompt))
+            # reset lane state (pos=0) and feed the prompt token by token
+            self.dstate = reset_lane(self.dstate, lane)
+            self.cur_tokens[lane] = prompt[0]
+            # oversized prompt: its page table will not fit the per-step
+            # lazy path gracefully — reserve one contiguous multi-superblock
+            # span up front (device large-object path) sized
+            # *decode-ahead*: the span covers every page the sequence can
+            # ever touch (max_seq, not just the prompt), so generation never
+            # needs a mid-decode lazy page or a span migration.  Clamped to
+            # the page-table width: generation stops at max_seq, so pages
+            # past it would never be touched.  A shared-prefix *hit* on a
+            # published span skips the reservation entirely: the lane
+            # acquires the published span instead.
+            table_width = int(self.dstate["block_table"].shape[1])
+            n_prompt_pages = min(-(-len(prompt) // self.cfg.page_size),
+                                 table_width)
+            hit = self.prefix_cache.lookup(prompt) if share_prefix else None
+            # longest-prefix match when the exact entry misses: a request
+            # matching k whole pages of a published prompt leases only those
+            # k pages' superblocks and decodes its suffix on its own lazily-
+            # allocated pages.  A mid-edge match first materializes the
+            # boundary as a trie split (durable when the node has a record).
+            pnode, pk = None, 0
+            if share_prefix and hit is None and self.cfg.attn_layers > 0:
+                pnode, pk = self.prefix_cache.match_partial(prompt)
+                if (pnode is not None
+                        and pk * self.cfg.page_size >= len(prompt)):
+                    pnode, pk = None, 0    # no suffix left: only the exact
+                    #                        entry may serve the whole prompt
+                elif pnode is not None and pk < pnode.end_page:
+                    m = self._split_node(pnode, pk)
+                    if m is None:          # no record blocks: fall back to
+                        #                    the deepest existing boundary
+                        pnode, pk = self.prefix_cache.deepest_boundary(
+                            pnode, pk)
+                    else:
+                        pnode = m
+                if pnode is None:
+                    pk = 0
+            if (self.cfg.attn_layers > 0 and hit is None and pnode is None
+                    and n_prompt_pages > self.acfg.sb_words):
+                n_ahead = min(-(-self.max_seq // self.cfg.page_size),
+                              table_width)
+                try:
+                    self._reserve_span(lane, max(n_prompt_pages, n_ahead))
+                except MemoryError:
+                    # back out the admission completely: session gone, lane
+                    # decode state neutral, lane in the pool exactly once —
+                    # the lane must be indistinguishable from never-admitted
+                    # (the old path handed the lane back with this request's
+                    # pos/block-table/cur-token still written into it)
+                    del self.sessions[lane]
+                    self.dstate = reset_lane(self.dstate, lane)
+                    self.cur_tokens[lane] = 0
+                    self.lane_states.release(lane)
+                    raise
+            if hit is not None:
+                if hit[0] == "span":
+                    # lease the published span's *prefix*: the prompt's KV
+                    # pages are exactly the prefix superblocks this lane will
+                    # read — no copy, no fresh reservation, and no claim on
+                    # the publisher's decode-ahead tail (which frees for
+                    # reuse the moment its own leases drop)
+                    _, off, n_span, full, plen, kvp, next_tok, lease_sbs = hit
+                    self.astate, _ = self._acquire_span(
+                        state=self.astate, off=jnp.int32(off),
+                        n_sbs=jnp.int32(lease_sbs))
+                    self.shared_spans[lane] = (off, full, lease_sbs)
+                    pages = off + np.arange(full, dtype=np.int32)
                 else:
-                    pnode = m
-            if pnode is None:
-                pk = 0
-        if (self.cfg.attn_layers > 0 and hit is None and pnode is None
-                and n_prompt_pages > self.acfg.sb_words):
-            n_ahead = min(-(-self.max_seq // self.cfg.page_size), table_width)
-            try:
-                self._reserve_span(lane, max(n_prompt_pages, n_ahead))
-            except MemoryError:
-                # back out the admission completely: session gone, lane
-                # decode state neutral, lane in the pool exactly once —
-                # the lane must be indistinguishable from never-admitted
-                # (the old path handed the lane back with this request's
-                # pos/block-table/cur-token still written into it)
-                del self.sessions[lane]
-                self.dstate = reset_lane(self.dstate, lane)
-                self.cur_tokens[lane] = 0
-                self.lane_states.release(lane)
-                raise
-        if hit is not None:
-            if hit[0] == "span":
-                # lease the published span's *prefix*: the prompt's KV
-                # pages are exactly the prefix superblocks this lane will
-                # read — no copy, no fresh reservation, and no claim on
-                # the publisher's decode-ahead tail (which frees for
-                # reuse the moment its own leases drop)
-                _, off, n_span, full, plen, kvp, next_tok, lease_sbs = hit
+                    _, pages, plen, kvp, next_tok = hit
+                    pages = np.asarray(pages, np.int32)
+                    for p in pages.tolist():
+                        self.prefix_cache.add_page_ref(p)
+                bt = _d2h(_D2H_BT, self.dstate["block_table"]).copy()
+                bt[lane, :len(pages)] = pages
+                self.dstate["block_table"] = jnp.asarray(bt)
+                kv = _d2h(_D2H_KV_POS, self.dstate["kv_pos"]).copy()
+                kv[lane, :len(pages)] = kvp
+                self.dstate["kv_pos"] = jnp.asarray(kv)
+                self.dstate["pos"] = self.dstate["pos"].at[lane].set(plen)
+                # the model's continuation at the prompt boundary was
+                # sampled by the publisher — it is part of the prefix
+                self.sessions[lane].tokens = list(prompt) + [next_tok]
+                self.cur_tokens[lane] = next_tok
+            elif pnode is not None and pk > 0:
+                # partial hit at a trie-node boundary: the node's span backs
+                # the whole prefix [0, pk) at identity offsets, so ONE
+                # acquire_span of the node's lease (= exactly the matched
+                # pages' superblocks) makes this an ordinary shared-span
+                # lane; the un-matched prompt suffix replays teacher-forced
+                # on the lane's own lazily-allocated pages
+                off, lease_sbs = pnode.span, pnode.lease_sbs
                 self.astate, _ = self._acquire_span(
                     state=self.astate, off=jnp.int32(off),
                     n_sbs=jnp.int32(lease_sbs))
-                self.shared_spans[lane] = (off, full, lease_sbs)
-                pages = off + np.arange(full, dtype=np.int32)
-            else:
-                _, pages, plen, kvp, next_tok = hit
-                pages = np.asarray(pages, np.int32)
-                for p in pages.tolist():
-                    self.prefix_cache.add_page_ref(p)
-            bt = np.asarray(self.dstate["block_table"]).copy()
-            bt[lane, :len(pages)] = pages
-            self.dstate["block_table"] = jnp.asarray(bt)
-            kv = np.asarray(self.dstate["kv_pos"]).copy()
-            kv[lane, :len(pages)] = kvp
-            self.dstate["kv_pos"] = jnp.asarray(kv)
-            self.dstate["pos"] = self.dstate["pos"].at[lane].set(plen)
-            # the model's continuation at the prompt boundary was
-            # sampled by the publisher — it is part of the prefix
-            self.sessions[lane].tokens = list(prompt) + [next_tok]
-            self.cur_tokens[lane] = next_tok
-        elif pnode is not None and pk > 0:
-            # partial hit at a trie-node boundary: the node's span backs
-            # the whole prefix [0, pk) at identity offsets, so ONE
-            # acquire_span of the node's lease (= exactly the matched
-            # pages' superblocks) makes this an ordinary shared-span
-            # lane; the un-matched prompt suffix replays teacher-forced
-            # on the lane's own lazily-allocated pages
-            off, lease_sbs = pnode.span, pnode.lease_sbs
-            self.astate, _ = self._acquire_span(
-                state=self.astate, off=jnp.int32(off),
-                n_sbs=jnp.int32(lease_sbs))
-            self.shared_spans[lane] = (off, pk, lease_sbs)
-            self.lane_states.partial_hits[lane] = pk
-            pages = off + np.arange(pk, dtype=np.int32)
-            bt = np.asarray(self.dstate["block_table"]).copy()
-            bt[lane, :pk] = pages
-            self.dstate["block_table"] = jnp.asarray(bt)
-            kv = np.asarray(self.dstate["kv_pos"]).copy()
-            page = self.cfg.page_size
-            kv[lane, :pk] = np.arange(pk * page,
-                                      dtype=np.int32).reshape(pk, page)
-            self.dstate["kv_pos"] = jnp.asarray(kv)
-            self.dstate["pos"] = self.dstate["pos"].at[lane].set(pk * page)
-            self.cur_tokens[lane] = prompt[pk * page]
-        # the allocator root for this lane points at its page table
-        self.astate = ja.set_root(self.astate, lane, jnp.int32(lane))
-        return lane
+                self.shared_spans[lane] = (off, pk, lease_sbs)
+                self.lane_states.partial_hits[lane] = pk
+                pages = off + np.arange(pk, dtype=np.int32)
+                bt = _d2h(_D2H_BT, self.dstate["block_table"]).copy()
+                bt[lane, :pk] = pages
+                self.dstate["block_table"] = jnp.asarray(bt)
+                kv = _d2h(_D2H_KV_POS, self.dstate["kv_pos"]).copy()
+                page = self.cfg.page_size
+                kv[lane, :pk] = np.arange(pk * page,
+                                          dtype=np.int32).reshape(pk, page)
+                self.dstate["kv_pos"] = jnp.asarray(kv)
+                self.dstate["pos"] = self.dstate["pos"].at[lane].set(pk * page)
+                self.cur_tokens[lane] = prompt[pk * page]
+            # the allocator root for this lane points at its page table
+            self.astate = ja.set_root(self.astate, lane, jnp.int32(lane))
+            return lane
 
     def _reserve_span(self, lane: int, n_pages: int) -> None:
         """Back ``n_pages`` page-table slots of ``lane`` with one
         contiguous large-object span (page ids = span offsets).  Raises
         ``MemoryError`` with the lane untouched; ``add_request`` owns
         backing the admission out."""
-        _OBS_DEV_ALLOC_LARGE.inc()
         self.astate, off = self._alloc_large(state=self.astate,
                                              nwords=jnp.int32(n_pages))
-        off = int(off)
+        off = int(_d2h(_D2H_SPAN_OFF, off))
         if off < 0:
             _OBS_SPAN_RESERVE_FAIL.inc()
             raise MemoryError(
                 f"KV arena cannot reserve a contiguous {n_pages}-page span")
         self.large_spans[lane] = (off, n_pages)
-        bt = np.asarray(self.dstate["block_table"]).copy()
+        bt = _d2h(_D2H_BT, self.dstate["block_table"]).copy()
         bt[lane, :n_pages] = off + np.arange(n_pages, dtype=np.int32)
         self.dstate["block_table"] = jnp.asarray(bt)
 
@@ -332,11 +358,10 @@ class ServingEngine:
         assert 0 < n <= self.publish_capacity
         need = np.zeros((self.lanes + self.publish_capacity,), bool)
         need[self.lanes:self.lanes + n] = True
-        _OBS_DEV_ALLOC.inc()
         self.astate, offs = self._alloc(state=self.astate,
                                         need=jnp.asarray(need))
         return [int(o) for o in
-                np.asarray(offs)[self.lanes:self.lanes + n]]
+                _d2h(_D2H_OFFS, offs)[self.lanes:self.lanes + n]]
 
     def _split_node(self, node: CacheNode, k: int) -> CacheNode | None:
         """Materialize page boundary ``k`` inside in-process trie node
@@ -456,12 +481,12 @@ class ServingEngine:
         Page-path entries are transient-only and complete here.  Returns
         True when a new entry was created."""
         s = self.sessions[lane]
-        pos = int(np.asarray(self.dstate["pos"][lane]))
+        pos = int(_d2h(_D2H_POS, self.dstate["pos"][lane]))
         page = self.cfg.page_size
         full = pos // page
         if full == 0:
             return False
-        kv = np.asarray(self.dstate["kv_pos"][lane])
+        kv = _d2h(_D2H_KV_POS, self.dstate["kv_pos"][lane])
         span = self.large_spans.get(lane)
         if span is None:
             shared = self.shared_spans.get(lane)  # sharers may re-publish
@@ -473,7 +498,7 @@ class ServingEngine:
             # entry: clamp to the leading block-table slots the span
             # actually backs (a sharer's post-prefix pages are its own
             # lazy allocations and hold *its* KV, not the span's)
-            bt_lane = np.asarray(self.dstate["block_table"][lane])
+            bt_lane = _d2h(_D2H_BT_ROW, self.dstate["block_table"][lane])
             cover = 0
             while (cover < min(full, n_span, bt_lane.size)
                    and int(bt_lane[cover]) == off + cover):
@@ -542,7 +567,7 @@ class ServingEngine:
             _OBS_PUB_QUEUED.inc()
             _OBS_PUB_DEPTH.set(len(self._publish_queue))
             return True
-        bt = np.asarray(self.dstate["block_table"][lane])
+        bt = _d2h(_D2H_BT_ROW, self.dstate["block_table"][lane])
         if pos != full * page:
             # share only a fully-processed, page-aligned prompt: a
             # mid-page publish would hand sharers a boundary token whose
@@ -670,45 +695,51 @@ class ServingEngine:
         active = self.lane_states.active()
         if not active.any():
             return {}
-        # page-boundary lanes need a fresh page before the step — unless
-        # the slot is already backed (prefix hit or a reserved large span)
-        pos = np.asarray(self.dstate["pos"])
-        page = self.cfg.page_size
-        need = active & (pos % page == 0) & (self.cfg.attn_layers > 0)
-        if need.any():
-            # only boundary steps pay the block-table device→host sync
-            bt_now = np.asarray(self.dstate["block_table"])
-            slot = np.clip(pos // page, 0, bt_now.shape[1] - 1)
-            need &= bt_now[np.arange(self.lanes), slot] < 0
-        if need.any():
-            _OBS_DEV_ALLOC.inc()
-            self.astate, offs = self._alloc(state=self.astate,
-                                            need=jnp.asarray(need))
-            offs = np.asarray(offs)
-            bt = np.asarray(self.dstate["block_table"]).copy()
-            for lane in np.nonzero(need)[0]:
-                if offs[lane] < 0:
-                    raise MemoryError("KV arena exhausted")
-                bt[lane, pos[lane] // page] = offs[lane]
-            self.dstate["block_table"] = jnp.asarray(bt)
+        with obs.span(_SP_STEP) as sp:
+            # page-boundary lanes need a fresh page before the step — unless
+            # the slot is already backed (prefix hit or a reserved large span)
+            pos = _d2h(_D2H_POS, self.dstate["pos"])
+            page = self.cfg.page_size
+            need = active & (pos % page == 0) & (self.cfg.attn_layers > 0)
+            if need.any():
+                # only boundary steps pay the block-table device→host sync
+                bt_now = _d2h(_D2H_BT, self.dstate["block_table"])
+                slot = np.clip(pos // page, 0, bt_now.shape[1] - 1)
+                need &= bt_now[np.arange(self.lanes), slot] < 0
+            if need.any():
+                with obs.span(_SP_PAGE_ALLOC):
+                    self.astate, offs = self._alloc(state=self.astate,
+                                                    need=jnp.asarray(need))
+                    offs = _d2h(_D2H_OFFS, offs)
+                    bt = _d2h(_D2H_BT, self.dstate["block_table"]).copy()
+                    for lane in np.nonzero(need)[0]:
+                        if offs[lane] < 0:
+                            raise MemoryError("KV arena exhausted")
+                        bt[lane, pos[lane] // page] = offs[lane]
+                    self.dstate["block_table"] = jnp.asarray(bt)
 
-        self.dstate, toks = self.step_fn(self.params, self.dstate,
-                                         jnp.asarray(self.cur_tokens))
-        toks = np.asarray(toks)
-        out = {}
-        for lane, s in list(self.sessions.items()):
-            if s.done:
-                continue
-            t = int(pos[lane]) + 1
-            if t < len(s.tokens):
-                self.cur_tokens[lane] = s.tokens[t]       # teacher-forced
-            else:
-                s.tokens.append(int(toks[lane]))
-                self.cur_tokens[lane] = int(toks[lane])
-                out[lane] = int(toks[lane])
-            if len(s.tokens) >= self.max_seq - 1:
-                self.finish(lane)
-        return out
+            with obs.span(_SP_DISPATCH):
+                self.dstate, toks = self.step_fn(self.params, self.dstate,
+                                                 jnp.asarray(self.cur_tokens))
+            toks = _d2h(_D2H_TOKENS, toks)
+            out = {}
+            n_prefill = 0
+            with obs.span(_SP_EMIT):
+                for lane, s in list(self.sessions.items()):
+                    if s.done:
+                        continue
+                    t = int(pos[lane]) + 1
+                    if t < len(s.tokens):
+                        self.cur_tokens[lane] = s.tokens[t]   # teacher-forced
+                        n_prefill += 1
+                    else:
+                        s.tokens.append(int(toks[lane]))
+                        self.cur_tokens[lane] = int(toks[lane])
+                        out[lane] = int(toks[lane])
+                    if len(s.tokens) >= self.max_seq - 1:
+                        self.finish(lane)
+            sp.args.update(prefill_lanes=n_prefill, decode_lanes=len(out))
+            return out
 
     def finish(self, lane: int) -> None:
         """Evict a session: free its pages (shared pages only at ref 0,
@@ -719,55 +750,56 @@ class ServingEngine:
         the same offset: a second ``finish`` of the lane raises
         (``KeyError``), it cannot silently release someone else's span.
         """
-        s = self.sessions.pop(lane)
-        s.done = True
-        bt = np.asarray(self.dstate["block_table"][lane])
-        pages = bt[bt >= 0].astype(np.int32)
-        span = self.large_spans.pop(lane, None)
-        shared = self.shared_spans.pop(lane, None)
-        self.lane_states.partial_hits.pop(lane, None)
-        if span is not None:
-            # the prompt's page table is one large span: free_large drops
-            # the owner's full-extent lease — superblocks nobody else
-            # leases free *now* (in particular the decode-ahead tail past
-            # the published prefix, which only prefix leases cover);
-            # pages decoded past the span were lazily allocated and go
-            # through the per-page free below
-            off, n_span = span
-            self.astate = self._free_large(state=self.astate,
-                                           off=jnp.int32(off),
-                                           n_sbs=jnp.int32(-1))
-            pages = pages[(pages < off) | (pages >= off + n_span)]
-        elif shared is not None:
-            # a sharer releases exactly the prefix range it leased; its
-            # own decode pages (which may legitimately reuse freed tail
-            # superblocks of this very span) free per-page below
-            off, n_backed, lease_sbs = shared
-            self.astate = self._free_large(state=self.astate,
-                                           off=jnp.int32(off),
-                                           n_sbs=jnp.int32(lease_sbs))
-            pages = pages[(pages < off) | (pages >= off + n_backed)]
-        keep = []
-        for p in pages.tolist():
-            if p in self.page_refs:
-                self.page_refs[p] -= 1
-                if self.page_refs[p] > 0:
-                    keep.append(p)          # still referenced elsewhere
-                else:
-                    del self.page_refs[p]
-        if keep:
-            pages = np.asarray([p for p in pages.tolist() if p not in keep],
-                               np.int32)
-        if pages.size:
-            offs = np.full((self.acfg.cache_cap,), -1, np.int32)
-            offs[:pages.size] = pages
-            self.astate = self._free(state=self.astate,
-                                     offs=jnp.asarray(offs),
-                                     mask=jnp.asarray(offs >= 0))
-        self.dstate["block_table"] = \
-            self.dstate["block_table"].at[lane].set(-1)
-        self.astate = ja.set_root(self.astate, lane, jnp.int32(-1))
-        self.lane_states.release(lane)
+        with obs.span(_SP_FINISH):
+            s = self.sessions.pop(lane)
+            s.done = True
+            bt = _d2h(_D2H_BT_ROW, self.dstate["block_table"][lane])
+            pages = bt[bt >= 0].astype(np.int32)
+            span = self.large_spans.pop(lane, None)
+            shared = self.shared_spans.pop(lane, None)
+            self.lane_states.partial_hits.pop(lane, None)
+            if span is not None:
+                # the prompt's page table is one large span: free_large drops
+                # the owner's full-extent lease — superblocks nobody else
+                # leases free *now* (in particular the decode-ahead tail past
+                # the published prefix, which only prefix leases cover);
+                # pages decoded past the span were lazily allocated and go
+                # through the per-page free below
+                off, n_span = span
+                self.astate = self._free_large(state=self.astate,
+                                               off=jnp.int32(off),
+                                               n_sbs=jnp.int32(-1))
+                pages = pages[(pages < off) | (pages >= off + n_span)]
+            elif shared is not None:
+                # a sharer releases exactly the prefix range it leased; its
+                # own decode pages (which may legitimately reuse freed tail
+                # superblocks of this very span) free per-page below
+                off, n_backed, lease_sbs = shared
+                self.astate = self._free_large(state=self.astate,
+                                               off=jnp.int32(off),
+                                               n_sbs=jnp.int32(lease_sbs))
+                pages = pages[(pages < off) | (pages >= off + n_backed)]
+            keep = []
+            for p in pages.tolist():
+                if p in self.page_refs:
+                    self.page_refs[p] -= 1
+                    if self.page_refs[p] > 0:
+                        keep.append(p)          # still referenced elsewhere
+                    else:
+                        del self.page_refs[p]
+            if keep:
+                pages = np.asarray(
+                    [p for p in pages.tolist() if p not in keep], np.int32)
+            if pages.size:
+                offs = np.full((self.acfg.cache_cap,), -1, np.int32)
+                offs[:pages.size] = pages
+                self.astate = self._free(state=self.astate,
+                                         offs=jnp.asarray(offs),
+                                         mask=jnp.asarray(offs >= 0))
+            self.dstate["block_table"] = \
+                self.dstate["block_table"].at[lane].set(-1)
+            self.astate = ja.set_root(self.astate, lane, jnp.int32(-1))
+            self.lane_states.release(lane)
 
     def check_occupancy(self) -> dict:
         """The allocator's live blocks must be exactly the pages the live
@@ -824,7 +856,7 @@ class ServingEngine:
         alive across a crash with no lane rooted on it."""
         S = jr.num_slots(self.acfg)
         R = int(self.dstate["block_table"].shape[1])
-        bt = np.asarray(self.dstate["block_table"])
+        bt = _d2h(_D2H_BT, self.dstate["block_table"])
         rows: dict[int, list[int]] = {}
         for lane, s in self.sessions.items():
             if s.done:
@@ -918,7 +950,7 @@ class ServingEngine:
             persistent = ja.persistent_snapshot(self.astate)
             roots = np.full((self.lanes + self.prefix_buckets,), -1,
                             np.int32)
-            bt = np.asarray(self.dstate["block_table"])
+            bt = _d2h(_D2H_BT, self.dstate["block_table"])
             for lane, s in self.sessions.items():
                 pages = bt[lane][bt[lane] >= 0]
                 if pages.size:
@@ -934,7 +966,8 @@ class ServingEngine:
             live_before = ja.live_blocks(self.astate, self.acfg)[PAGE_CLS]
             self.astate = new_state
             live_after = ja.live_blocks(new_state, self.acfg)[PAGE_CLS]
-            sp.add(int(np.asarray(marked).sum()))
+            n_marked = int(_d2h(_D2H_MARKED, marked).sum())
+            sp.add(n_marked)
         _phase(sp)
         # drop + recount the engine's transient sharing records (recovery
         # step 2: caches start empty in a fresh process).  Span-backed
@@ -980,7 +1013,7 @@ class ServingEngine:
                                                   + [-1], np.int32),
                                        seal_ok=jnp.asarray(seal_ok))
             survivors = self.prefix_store.prune(
-                np.asarray(live)[:len(recs)])
+                _d2h(_D2H_LIVE, live)[:len(recs)])
             page = self.cfg.page_size
             for rec in survivors:
                 # a fully-processed prompt page p holds positions
@@ -991,7 +1024,6 @@ class ServingEngine:
                 self._prefix_cache[rec.key] = (
                     "span", rec.span, rec.span_pages, rec.n_pages,
                     rec.n_pages * page, kvp, rec.next_tok, rec.lease_sbs)
-                _OBS_DEV_TRIM.inc()
                 self.astate, _ = self._trim_large(
                     state=self.astate, off=jnp.int32(rec.span),
                     n_keep=jnp.int32(rec.lease_sbs), n_held=jnp.int32(-1))
@@ -1010,13 +1042,12 @@ class ServingEngine:
             for lane, (off, _n_backed,
                        lease_sbs) in self.shared_spans.items():
                 if lane in self.sessions and not self.sessions[lane].done:
-                    _OBS_DEV_TRIM.inc()
                     self.astate, _ = self._trim_large(
                         state=self.astate, off=jnp.int32(off),
                         n_keep=jnp.int32(lease_sbs), n_held=jnp.int32(-1))
                     sp.add(1)
         _phase(sp)
-        return {"marked": int(np.asarray(marked).sum()),
+        return {"marked": n_marked,
                 "live_before": live_before, "live_after": live_after,
                 "index_records": len(survivors),
                 "trie_pruned": trie_pruned,

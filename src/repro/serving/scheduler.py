@@ -40,9 +40,10 @@ class EngineBusy(RuntimeError):
 _OBS_REJECTS = obs.counter("sched.rejects")
 _OBS_PARK_RETRY = obs.counter("sched.park_retries")
 _OBS_QUEUE_DEPTH = obs.gauge("sched.queue_depth")
-_OBS_QUEUE_DEPTH_H = obs.histogram("sched.queue_depth_at_submit")
 _OBS_TTFT = obs.histogram("serve.ttft_seconds")
 _OBS_LATENCY = obs.histogram("serve.latency_seconds")
+_SP_STEP = "sched.step"
+_SP_ADMIT = "sched.admit"
 
 
 @dataclasses.dataclass
@@ -122,29 +123,29 @@ class Scheduler:
                     f"queue is full ({self.max_waiting})")
             self.waiting.append(req)
         _OBS_QUEUE_DEPTH.set(len(self.waiting))
-        _OBS_QUEUE_DEPTH_H.observe(len(self.waiting))
         return rid
 
     def _admit(self, req: Request) -> bool:
         eng = self.engine
         if not eng.free_lanes:
             return False
-        try:
-            req.lane = eng.add_request(req.prompt,
-                                       share_prefix=req.share_prefix)
-        except EngineBusy:
-            return False
-        except MemoryError:
-            # span reservation failed; the engine neutralized the lane.
-            # Spans free as other requests finish, so park and retry —
-            # unless nothing is running, in which case it can never fit.
-            if not self.active:
-                raise
-            _OBS_PARK_RETRY.inc()
-            return False
-        req.session = eng.sessions[req.lane]
-        self.active[req.lane] = req
-        return True
+        with obs.span(_SP_ADMIT):
+            try:
+                req.lane = eng.add_request(req.prompt,
+                                           share_prefix=req.share_prefix)
+            except EngineBusy:
+                return False
+            except MemoryError:
+                # span reservation failed; the engine neutralized the lane.
+                # Spans free as other requests finish, so park and retry —
+                # unless nothing is running, in which case it can never fit.
+                if not self.active:
+                    raise
+                _OBS_PARK_RETRY.inc()
+                return False
+            req.session = eng.sessions[req.lane]
+            self.active[req.lane] = req
+            return True
 
     def _admit_waiting(self) -> None:
         while self.waiting and self.engine.free_lanes:
@@ -157,32 +158,33 @@ class Scheduler:
         """One continuous-batching tick: admit → decode → collect
         finishes → maybe flush the publish queue.  Returns
         ``rid -> emitted token`` for lanes that sampled this step."""
-        eng = self.engine
-        self._admit_waiting()
-        emitted = eng.step()
-        self._steps += 1
-        out: dict[int, int] = {}
-        for lane, req in list(self.active.items()):
-            if lane in emitted:
-                out[req.rid] = emitted[lane]
-                if req.t_first is None:
-                    req.t_first = time.perf_counter()
-                    _OBS_TTFT.observe(req.t_first - req.t_submit)
-            sess = eng.sessions.get(lane)
-            if sess is None or sess.done:
-                self._complete(lane, req)        # engine auto-finished it
-            elif (req.max_new_tokens is not None
-                    and len(sess.tokens)
-                    >= len(req.prompt) + req.max_new_tokens):
-                if req.publish:
-                    eng.queue_publish(lane)
-                eng.finish(lane)
-                self._complete(lane, req)
-        if (eng.pending_publishes >= eng.publish_capacity
-                or (eng.pending_publishes
-                    and self._steps % self.publish_every == 0)):
-            eng.flush_publishes()
-        return out
+        with obs.span(_SP_STEP):
+            eng = self.engine
+            self._admit_waiting()
+            emitted = eng.step()
+            self._steps += 1
+            out: dict[int, int] = {}
+            for lane, req in list(self.active.items()):
+                if lane in emitted:
+                    out[req.rid] = emitted[lane]
+                    if req.t_first is None:
+                        req.t_first = time.perf_counter()
+                        _OBS_TTFT.observe(req.t_first - req.t_submit)
+                sess = eng.sessions.get(lane)
+                if sess is None or sess.done:
+                    self._complete(lane, req)        # engine auto-finished it
+                elif (req.max_new_tokens is not None
+                        and len(sess.tokens)
+                        >= len(req.prompt) + req.max_new_tokens):
+                    if req.publish:
+                        eng.queue_publish(lane)
+                    eng.finish(lane)
+                    self._complete(lane, req)
+            if (eng.pending_publishes >= eng.publish_capacity
+                    or (eng.pending_publishes
+                        and self._steps % self.publish_every == 0)):
+                eng.flush_publishes()
+            return out
 
     def _complete(self, lane: int, req: Request) -> None:
         del self.active[lane]

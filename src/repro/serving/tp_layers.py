@@ -146,35 +146,44 @@ def attn_decode_tp(cfg, p, x, pos, arena_k, arena_v, block_table, kv_pos,
     pid_w = jnp.where(mine & (pid >= 0), pid, dump)
     lslot = jnp.where(mine, slot % page_loc, 0)
     b_ix = jnp.arange(B)
-    if scales is not None:
-        # int8 KV (KIVI-style per-slot-per-head scales): quantize the new
-        # token's k/v, store int8 + fp32 scale; dequantize on gather
-        ks, vs = scales
-        k_s = jnp.max(jnp.abs(k_new.astype(jnp.float32)), -1) / 127.0 + 1e-9
-        v_s = jnp.max(jnp.abs(v_new.astype(jnp.float32)), -1) / 127.0 + 1e-9
-        kq = jnp.clip(jnp.round(k_new.astype(jnp.float32)
-                                / k_s[..., None]), -127, 127).astype(jnp.int8)
-        vq = jnp.clip(jnp.round(v_new.astype(jnp.float32)
-                                / v_s[..., None]), -127, 127).astype(jnp.int8)
-        arena_k = arena_k.at[pid_w, lslot].set(kq)
-        arena_v = arena_v.at[pid_w, lslot].set(vq)
-        ks = ks.at[pid_w, lslot].set(k_s)
-        vs = vs.at[pid_w, lslot].set(v_s)
-    else:
-        arena_k = arena_k.at[pid_w, lslot].set(k_new.astype(arena_k.dtype))
-        arena_v = arena_v.at[pid_w, lslot].set(v_new.astype(arena_v.dtype))
-    kv_pos = kv_pos.at[b_ix, lpage, lslot].set(
-        jnp.where(mine & (pid >= 0), pos, kv_pos[b_ix, lpage, lslot]))
+    with jax.named_scope("kv_write"):
+        if scales is not None:
+            # int8 KV (KIVI-style per-slot-per-head scales): quantize the new
+            # token's k/v, store int8 + fp32 scale; dequantize on gather
+            ks, vs = scales
+            k_s = (jnp.max(jnp.abs(k_new.astype(jnp.float32)), -1)
+                   / 127.0 + 1e-9)
+            v_s = (jnp.max(jnp.abs(v_new.astype(jnp.float32)), -1)
+                   / 127.0 + 1e-9)
+            kq = jnp.clip(jnp.round(k_new.astype(jnp.float32)
+                                    / k_s[..., None]),
+                          -127, 127).astype(jnp.int8)
+            vq = jnp.clip(jnp.round(v_new.astype(jnp.float32)
+                                    / v_s[..., None]),
+                          -127, 127).astype(jnp.int8)
+            arena_k = arena_k.at[pid_w, lslot].set(kq)
+            arena_v = arena_v.at[pid_w, lslot].set(vq)
+            ks = ks.at[pid_w, lslot].set(k_s)
+            vs = vs.at[pid_w, lslot].set(v_s)
+        else:
+            arena_k = arena_k.at[pid_w, lslot].set(
+                k_new.astype(arena_k.dtype))
+            arena_v = arena_v.at[pid_w, lslot].set(
+                v_new.astype(arena_v.dtype))
+        kv_pos = kv_pos.at[b_ix, lpage, lslot].set(
+            jnp.where(mine & (pid >= 0), pos, kv_pos[b_ix, lpage, lslot]))
 
-    # local paged gather + partial softmax
-    bt = jnp.where(block_table < 0, dump, block_table)
-    kloc = arena_k[bt].reshape(B, P * page_loc, kvh, dh)
-    vloc = arena_v[bt].reshape(B, P * page_loc, kvh, dh)
-    if scales is not None:
-        ksl = ks[bt].reshape(B, P * page_loc, kvh)[..., None]
-        vsl = vs[bt].reshape(B, P * page_loc, kvh)[..., None]
-        kloc = (kloc.astype(jnp.float32) * ksl).astype(x.dtype)
-        vloc = (vloc.astype(jnp.float32) * vsl).astype(x.dtype)
+    # local paged gather
+    with jax.named_scope("kv_gather"):
+        bt = jnp.where(block_table < 0, dump, block_table)
+        kloc = arena_k[bt].reshape(B, P * page_loc, kvh, dh)
+        vloc = arena_v[bt].reshape(B, P * page_loc, kvh, dh)
+        if scales is not None:
+            ksl = ks[bt].reshape(B, P * page_loc, kvh)[..., None]
+            vsl = vs[bt].reshape(B, P * page_loc, kvh)[..., None]
+            kloc = (kloc.astype(jnp.float32) * ksl).astype(x.dtype)
+            vloc = (vloc.astype(jnp.float32) * vsl).astype(x.dtype)
+    # partial softmax
     qg = q.reshape(B, kvh, g, dh)
     s = jnp.einsum("bkgd,btkd->bkgt", qg, kloc,
                    preferred_element_type=jnp.float32) * (dh ** -0.5)

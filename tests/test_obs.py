@@ -9,9 +9,11 @@ exact recovery-stats contract (phase names + stat keys pinned, so a
 rename fails loudly instead of silently vanishing from dashboards).
 """
 
+import contextlib
 import json
 import os
 import tempfile
+import time
 
 import numpy as np
 import pytest
@@ -132,6 +134,77 @@ def test_span_phases_accumulate_and_trace_exports():
     reg.reset_all()
     assert reg.chrome_trace()["traceEvents"] == []
     assert reg.snapshot()["phases"] == {}
+
+
+def _logging_annotator(log):
+    @contextlib.contextmanager
+    def annotate(name):
+        log.append(("enter", name))
+        yield
+        log.append(("exit", name))
+    return annotate
+
+
+def test_annotator_wraps_each_span_in_order():
+    reg = Registry()
+    log = []
+    reg.annotator = _logging_annotator(log)
+    with reg.span("t.outer"):
+        with reg.span("t.inner") as sp:
+            log.append("work")
+            sp.args.update(lanes=3)
+    assert log == [("enter", "t.outer"), ("enter", "t.inner"), "work",
+                   ("exit", "t.inner"), ("exit", "t.outer")]
+    # the annotator changes nothing the registry records
+    assert reg.snapshot()["phases"]["t.inner"]["calls"] == 1
+    inner = [e for e in reg.chrome_trace()["traceEvents"]
+             if e["name"] == "t.inner"]
+    assert inner[0]["args"] == {"lanes": 3}
+
+
+@pytest.mark.parametrize("where", ["registry", "default"])
+def test_annotator_is_never_called_while_disabled(where):
+    log = []
+    if where == "registry":
+        reg = Registry(enabled=False)
+        reg.annotator = _logging_annotator(log)
+        span = reg.span
+    else:
+        prev = obs.get_registry().annotator
+        obs.set_annotator(_logging_annotator(log))
+        obs.disable()
+        span = obs.span
+    try:
+        with span("t.quiet") as sp:
+            pass
+    finally:
+        if where == "default":
+            obs.enable()
+            obs.set_annotator(prev)
+    assert log == [] and sp.seconds >= 0.0
+
+
+def test_span_buffer_keeps_the_newest_and_says_what_it_let_go(monkeypatch):
+    monkeypatch.setattr(Registry, "TRACE_CAP", 4)
+    reg = Registry()
+    for i in range(2):
+        with reg.span(f"t.old{i}"):
+            pass
+    mid = time.perf_counter()
+    for i in range(4):
+        with reg.span(f"t.new{i}", i=i):
+            pass
+    kept = reg.span_events(mid)
+    assert [e[0] for e in kept] == [f"t.new{i}" for i in range(4)]
+    assert all(mid <= s <= e for _, s, e, _ in kept)
+    assert [e[3] for e in kept] == [{"i": i} for i in range(4)]
+    assert reg.span_events() is None         # the two old ones are gone
+    trace = reg.chrome_trace()
+    assert trace["otherData"]["dropped"] == 2
+    assert [e["name"] for e in trace["traceEvents"]] == \
+        [f"t.new{i}" for i in range(4)]
+    reg.reset_all()
+    assert reg.span_events() == []
 
 
 # ---------------------------------------------------------------------------

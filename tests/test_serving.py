@@ -588,3 +588,151 @@ def test_check_occupancy_catches_a_page_no_lane_holds(mesh):
                              jnp.asarray(need))
     with pytest.raises(AssertionError, match="occupancy"):
         eng.check_occupancy()
+
+
+# ---------------------------------------------------------------------------
+# the engine's own spans and counters (repro.obs)
+# ---------------------------------------------------------------------------
+PROMPTS = ([3, 1, 4, 1, 5], [2, 7, 1, 8, 2, 8, 1, 8, 2, 8, 4], [9] * 19)
+MAX_NEW = (6, 3, 12)
+
+
+class _OpenSpans:
+    """An ``obs`` annotator that keeps the names of the open spans."""
+
+    def __init__(self):
+        self.stack = []
+
+    def __call__(self, name):
+        self.name = name
+        return self
+
+    def __enter__(self):
+        self.stack.append(self.name)
+
+    def __exit__(self, *exc):
+        self.stack.pop()
+
+
+class _ReadsOf:
+    """``numpy`` for the engine module, recording the innermost open
+    span at every ``asarray`` of a device array (a device->host read)."""
+
+    def __init__(self, spans, reads):
+        self._spans, self._reads = spans, reads
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    def asarray(self, x, *a, **kw):
+        if isinstance(x, jax.Array):
+            self._reads.append(self._spans.stack[-1]
+                               if self._spans.stack else None)
+        return np.asarray(x, *a, **kw)
+
+
+@pytest.fixture(scope="module")
+def served(mesh):
+    """Three requests served to the end through the scheduler on two
+    lanes (page 8), with the engine's allocator calls and device->host
+    reads recorded from the outside, and the registry's record of the
+    run."""
+    from repro import obs
+    from repro.serving import engine as engine_mod
+    from repro.serving.scheduler import Scheduler
+    cfg = dataclasses.replace(get_smoke_config("starcoder2_3b"), page_size=8)
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(cfg, mesh, params, lanes=2, max_seq=64)
+    calls = {"alloc": 0}
+    alloc = eng._alloc
+
+    def counted(**kw):
+        calls["alloc"] += 1
+        return alloc(**kw)
+    eng._alloc = counted
+    sched = Scheduler(eng)
+    spans, reads = _OpenSpans(), []
+    obs.reset_all()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(obs.get_registry(), "annotator", spans)
+        mp.setattr(engine_mod, "np", _ReadsOf(spans, reads))
+        for p, n in zip(PROMPTS, MAX_NEW):
+            sched.submit(p, max_new_tokens=n)
+        results = sched.drain()
+    snap = obs.snapshot()
+    steps = [a for name, _, _, a in obs.span_events() if name == "engine.step"]
+    return dict(eng=eng, calls=calls, results=results, reads=reads,
+                steps=steps, phases=snap["phases"])
+
+
+def test_engine_counts_prompt_and_sampled_lane_steps(served):
+    steps = served["steps"]
+    assert (sum(a["prefill_lanes"] for a in steps)
+            == sum(len(p) - 1 for p in PROMPTS))
+    assert sum(a["decode_lanes"] for a in steps) == sum(MAX_NEW)
+    assert all(len(served["results"][i]) == len(p) + n
+               for i, (p, n) in enumerate(zip(PROMPTS, MAX_NEW)))
+    assert len(steps) == served["phases"]["engine.step"]["calls"]
+
+
+def test_engine_page_alloc_span_per_boundary_allocation(served):
+    ph = served["phases"]
+    assert served["calls"]["alloc"] > 0
+    assert ph["engine.page_alloc"]["calls"] == served["calls"]["alloc"]
+    # each allocation reads the offsets it got and the block table
+    assert ph["engine.d2h.offs"]["calls"] == served["calls"]["alloc"]
+
+
+def test_engine_finish_timed_once_per_finish(served):
+    ph = served["phases"]
+    assert ph["engine.finish"]["calls"] == len(PROMPTS)
+    assert ph["engine.finish"]["seconds"] > 0
+    assert ph["engine.add_request"]["calls"] == len(PROMPTS)
+    assert ph["sched.admit"]["calls"] == len(PROMPTS)
+    assert ph["sched.step"]["calls"] == ph["engine.step"]["calls"]
+
+
+def test_every_device_read_is_a_counted_span(served):
+    """Each device->host read of the served path happens inside its own
+    ``engine.d2h.*`` span, and each such span is one read."""
+    reads = served["reads"]
+    assert reads
+    assert all(r is not None and r.startswith("engine.d2h.") for r in reads)
+    assert len(reads) == sum(row["calls"]
+                             for name, row in served["phases"].items()
+                             if name.startswith("engine.d2h."))
+
+
+@pytest.mark.parametrize("boundary", [False, True])
+def test_engine_step_reads_the_device_a_documented_number_of_times(
+        mesh, boundary):
+    """A plain step reads ``pos`` and the tokens; a step where a lane
+    crosses a page also reads the block table twice and the offsets the
+    allocator returned."""
+    from repro import obs
+    cfg = dataclasses.replace(get_smoke_config("starcoder2_3b"), page_size=8)
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(cfg, mesh, params, lanes=2, max_seq=64)
+    eng.add_request([5, 9, 3])
+    for _ in range(8 if boundary else 1):      # to pos 8, or to pos 1
+        eng.step()
+    obs.reset_all()
+    eng.step()
+    snap = obs.snapshot()
+    want = {"engine.d2h.pos": 1, "engine.d2h.tokens": 1}
+    if boundary:
+        want.update({"engine.d2h.block_table": 2, "engine.d2h.offs": 1})
+    assert {n: row["calls"] for n, row in snap["phases"].items()
+            if n.startswith("engine.d2h.")} == want
+    assert snap["phases"]["engine.step"]["calls"] == 1
+
+
+@pytest.mark.parametrize("scope", ["kv_gather", "kv_write", "attention",
+                                   "ffn", "logits"])
+def test_decode_step_carries_its_named_scopes(served, scope):
+    import re
+    eng = served["eng"]
+    text = eng.step_fn.lower(eng.params, eng.dstate,
+                             jnp.asarray(eng.cur_tokens)).as_text(
+                                 dialect="hlo", debug_info=True)
+    assert re.search(rf'op_name="([^"]*/)?{scope}/', text)
