@@ -1,12 +1,18 @@
 """serve_step (decode): shard_map assembly over (pod, data, model).
 
 One decode step = embed → scan over pattern units (each unit applies its
-mixers/ffns via ``tp_layers``) → final norm → vocab-parallel logits →
-greedy sample.  Named scopes (``attention``, with ``kv_write`` and
-``kv_gather`` inside it, ``ffn``, ``logits``) label the compiled ops in
-a profile.  Batch and KV pages are sharded over the data axes
-(shard-local page ids — one arena per data shard); ``model`` carries
-Megatron-style TP plus slot-sharded paged attention.
+mixers/ffns via ``tp_layers``) → tail layers → final norm →
+vocab-parallel logits → greedy sample.  The unit-stacked decode state
+is the scan's carry, not a scanned input and output: attention writes
+the new token's K/V into the stacked arena [U, pages, page, K, dh] at
+``[unit, page, slot]`` and gathers ``[unit, block_table]`` in place, and
+a recurrent mixer reads its unit's slice of its small per-batch state
+and writes it back, so neither a layer nor the step copies the arena.
+Named scopes (``attention``, with ``kv_write`` and ``kv_gather`` inside
+it, ``ffn``, ``logits``) label the compiled ops in a profile.  Batch
+and KV pages are sharded over the data axes (shard-local page ids — one
+arena per data shard); ``model`` carries Megatron-style TP plus
+slot-sharded paged attention.
 
 The decode state is a pytree:
 
@@ -221,7 +227,10 @@ def make_dstate(cfg: ModelConfig, *, batch: int, max_seq: int,
 # the step itself
 # ---------------------------------------------------------------------------
 def _apply_layer_tp(cfg, spec, p, x, pos, block_table, kv_pos, state,
-                    seq_dp_axes=()):
+                    seq_dp_axes=(), unit=None):
+    """One (mixer, ffn) layer.  With ``unit`` the state is stacked over
+    pattern units: attention indexes the stacked arena in place, a
+    recurrent mixer reads and writes back its unit's slice."""
     mixer, ffn = spec
     M = MODEL_AXIS
     h = apply_norm(cfg.norm, p["norm1"], x)
@@ -234,14 +243,23 @@ def _apply_layer_tp(cfg, spec, p, x, pos, block_table, kv_pos, state,
             y, ak, av, kv_pos2, nsc = tpl.attn_decode_tp(
                 cfg, p["attn"], h, pos, state["k"], state["v"], block_table,
                 kv_pos, window=win, axis=M, seq_dp_axes=seq_dp_axes,
-                scales=scales)
+                scales=scales, unit=unit)
         new_state = {"k": ak, "v": av}
         if nsc is not None:
             new_state["ks"], new_state["vs"] = nsc
-    elif mixer == "mamba2":
-        y, new_state = tpl.mamba2_decode_tp(cfg, p["ssd"], h, state, M)
-    elif mixer == "rglru":
-        y, new_state = tpl.rglru_decode_tp(cfg, p["rglru"], h, state, M)
+    elif mixer in ("mamba2", "rglru"):
+        decode = (tpl.mamba2_decode_tp if mixer == "mamba2"
+                  else tpl.rglru_decode_tp)
+        weights = p["ssd" if mixer == "mamba2" else "rglru"]
+        if unit is None:
+            y, new_state = decode(cfg, weights, h, state, M)
+        else:
+            y, ns = decode(cfg, weights, h, jax.tree.map(
+                lambda a: lax.dynamic_index_in_dim(a, unit, 0, False),
+                state), M)
+            new_state = jax.tree.map(
+                lambda a, n: lax.dynamic_update_index_in_dim(a, n, unit, 0),
+                state, ns)
     x = x + y
     if ffn != "none":
         h = apply_norm(cfg.norm, p["norm2"], x)
@@ -253,6 +271,28 @@ def _apply_layer_tp(cfg, spec, p, x, pos, block_table, kv_pos, state,
     return x, new_state
 
 
+def _apply_units(cfg, seq_dp_axes, units_p, units_s, x, pos, block_table,
+                 kv_pos):
+    """The layer loop over pattern units.  The unit-stacked state rides in
+    ``lax.scan``'s carry, so every layer updates it in place: no layer
+    slices its arena out and no step copies the arena."""
+
+    def body(carry, inp):
+        x, units_s = carry
+        unit_p, u = inp
+        units_s = dict(units_s)
+        for i, spec in enumerate(cfg.pattern):
+            key = f"l{i}"
+            x, units_s[key] = _apply_layer_tp(
+                cfg, spec, unit_p[key], x, pos, block_table, kv_pos,
+                units_s[key], seq_dp_axes, unit=u)
+        return (x, units_s), None
+
+    (x, units_s), _ = lax.scan(
+        body, (x, units_s), (units_p, jnp.arange(cfg.full_units)))
+    return x, units_s
+
+
 def _decode_local(cfg: ModelConfig, seq_dp_axes, params, dstate, tokens,
                   return_logits: bool = False, vocab_sharded: bool = True):
     """Runs per-device inside shard_map."""
@@ -261,19 +301,8 @@ def _decode_local(cfg: ModelConfig, seq_dp_axes, params, dstate, tokens,
     block_table = dstate["block_table"]
     kv_pos = dstate["kv_pos"]
     x = tpl.embed_tp(params["embed"], tokens, M, sharded=vocab_sharded)
-
-    def body(x, inp):
-        unit_p, unit_s = inp
-        new_s = {}
-        for i, spec in enumerate(cfg.pattern):
-            st = unit_s.get(f"l{i}")
-            x, ns = _apply_layer_tp(cfg, spec, unit_p[f"l{i}"], x, pos,
-                                    block_table, kv_pos, st, seq_dp_axes)
-            if ns is not None:
-                new_s[f"l{i}"] = ns
-        return x, new_s
-
-    x, new_units = lax.scan(body, x, (params["units"], dstate["units"]))
+    x, new_units = _apply_units(cfg, seq_dp_axes, params["units"],
+                                dstate["units"], x, pos, block_table, kv_pos)
     new_tail = {}
     for i, spec in enumerate(cfg.tail_specs):
         st = dstate["tail"].get(f"t{i}")

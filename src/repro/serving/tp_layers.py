@@ -92,28 +92,39 @@ def dp_linear_index(dp_axes) -> jax.Array:
 
 def attn_decode_tp(cfg, p, x, pos, arena_k, arena_v, block_table, kv_pos,
                    *, window: int = 0, axis: str = "model",
-                   seq_dp_axes: tuple = (), scales=None):
+                   seq_dp_axes: tuple = (), scales=None, unit=None):
     """One-token paged attention.
 
     x:           [B, D] replicated over ``axis``
-    arena_k/v:   [pages_loc, page_loc, K, dh] local slot shard (+1 dump page)
+    arena_k/v:   [U, pages_loc, page_loc, K, dh] when ``unit`` (an i32
+                 scalar) is given: the arena of every pattern unit,
+                 carried through the layer loop and indexed in place at
+                 ``unit``, both where the new token is written and where
+                 pages are gathered; [pages_loc, page_loc, K, dh] (one
+                 layer's arena, a tail layer's) when it is not.  Local
+                 slot shard, +1 dump page.
     block_table: [B, P_loc] shard-local page ids (-1 unused)
     kv_pos:      [B, P_loc, page_loc] position per local slot (-1 invalid)
+    scales:      int8 K/V's (ks, vs), each the arena's shape without its
+                 last axis: [U, pages_loc, page_loc, K] or
+                 [pages_loc, page_loc, K]
 
     When ``seq_dp_axes`` is non-empty, one sequence's *pages* are sharded
     across those data axes (sequence parallelism for batch < dp, e.g. the
     long_500k shape) and the softmax merge spans (dp_axes + model).
 
-    Returns (y [B, D], arena_k', arena_v', kv_pos').
+    Returns (y [B, D], arena_k', arena_v', kv_pos', scales'), the arenas
+    and scales in the shapes they came in.
     """
     B, D = x.shape
     h, kvh, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     g = h // kvh
     tp = _tp(axis)
-    page_loc = arena_k.shape[1]
+    page_loc = arena_k.shape[-3]
     page = page_loc * tp
     P = block_table.shape[1]
-    dump = arena_k.shape[0] - 1
+    dump = arena_k.shape[-4] - 1
+    at = () if unit is None else (unit,)      # leading index into the arena
     merge_axes = tuple(seq_dp_axes) + (axis,)
 
     # fused row-parallel qkv: one psum
@@ -161,14 +172,14 @@ def attn_decode_tp(cfg, p, x, pos, arena_k, arena_v, block_table, kv_pos,
             vq = jnp.clip(jnp.round(v_new.astype(jnp.float32)
                                     / v_s[..., None]),
                           -127, 127).astype(jnp.int8)
-            arena_k = arena_k.at[pid_w, lslot].set(kq)
-            arena_v = arena_v.at[pid_w, lslot].set(vq)
-            ks = ks.at[pid_w, lslot].set(k_s)
-            vs = vs.at[pid_w, lslot].set(v_s)
+            arena_k = arena_k.at[at + (pid_w, lslot)].set(kq)
+            arena_v = arena_v.at[at + (pid_w, lslot)].set(vq)
+            ks = ks.at[at + (pid_w, lslot)].set(k_s)
+            vs = vs.at[at + (pid_w, lslot)].set(v_s)
         else:
-            arena_k = arena_k.at[pid_w, lslot].set(
+            arena_k = arena_k.at[at + (pid_w, lslot)].set(
                 k_new.astype(arena_k.dtype))
-            arena_v = arena_v.at[pid_w, lslot].set(
+            arena_v = arena_v.at[at + (pid_w, lslot)].set(
                 v_new.astype(arena_v.dtype))
         kv_pos = kv_pos.at[b_ix, lpage, lslot].set(
             jnp.where(mine & (pid >= 0), pos, kv_pos[b_ix, lpage, lslot]))
@@ -176,11 +187,11 @@ def attn_decode_tp(cfg, p, x, pos, arena_k, arena_v, block_table, kv_pos,
     # local paged gather
     with jax.named_scope("kv_gather"):
         bt = jnp.where(block_table < 0, dump, block_table)
-        kloc = arena_k[bt].reshape(B, P * page_loc, kvh, dh)
-        vloc = arena_v[bt].reshape(B, P * page_loc, kvh, dh)
+        kloc = arena_k[at + (bt,)].reshape(B, P * page_loc, kvh, dh)
+        vloc = arena_v[at + (bt,)].reshape(B, P * page_loc, kvh, dh)
         if scales is not None:
-            ksl = ks[bt].reshape(B, P * page_loc, kvh)[..., None]
-            vsl = vs[bt].reshape(B, P * page_loc, kvh)[..., None]
+            ksl = ks[at + (bt,)].reshape(B, P * page_loc, kvh)[..., None]
+            vsl = vs[at + (bt,)].reshape(B, P * page_loc, kvh)[..., None]
             kloc = (kloc.astype(jnp.float32) * ksl).astype(x.dtype)
             vloc = (vloc.astype(jnp.float32) * vsl).astype(x.dtype)
     # partial softmax

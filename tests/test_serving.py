@@ -59,6 +59,84 @@ def test_decode_matches_oracle(arch, fp32, mesh):
     _parity(cfg, mesh, tol=1e-3 if fp32 else 3e-2)
 
 
+def _units_by_layer(cfg, seq_dp_axes, units_p, units_s, x, pos,
+                    block_table, kv_pos):
+    """Reference layer loop: each unit's weights and 4-D arena taken out
+    of the stack, every layer applied one by one, the states restacked."""
+    per_unit = []
+    for u in range(cfg.full_units):
+        unit_p = jax.tree.map(lambda a: a[u], units_p)
+        unit_s = jax.tree.map(lambda a: a[u], units_s)
+        for i, spec in enumerate(cfg.pattern):
+            x, unit_s[f"l{i}"] = dec._apply_layer_tp(
+                cfg, spec, unit_p[f"l{i}"], x, pos, block_table, kv_pos,
+                unit_s[f"l{i}"], seq_dp_axes)
+        per_unit.append(unit_s)
+    return x, jax.tree.map(lambda *a: jnp.stack(a), *per_unit)
+
+
+def _served_decode(cfg, mesh, params, toks, steps):
+    """``steps`` decode steps of ``toks`` on two lanes whose pages are
+    scattered over the arena; every output and the final state."""
+    pshape = jax.eval_shape(lambda: params)
+    step, _, _ = dec.make_decode_step(cfg, mesh, pshape, return_logits=True)
+    ds = dec.make_dstate(cfg, batch=2, max_seq=32, dp_shards=1)
+    Pn = ds["block_table"].shape[1]
+    ds["block_table"] = jnp.asarray(np.random.default_rng(0).permutation(
+        2 * Pn).astype(np.int32).reshape(2, Pn))
+    outs = []
+    for t in range(steps):
+        ds, tok, lg = step(params, ds, toks[:, t])
+        outs.append((tok, lg))
+    return jax.device_get((outs, ds))
+
+
+def _with_attn_tail(cfg):
+    return dataclasses.replace(cfg, num_layers=5,
+                               pattern=(("attn", "mlp"), ("attn", "mlp")))
+
+
+@pytest.mark.parametrize("arch,change", [
+    ("starcoder2_3b", None),                   # GQA + bias
+    ("granite_20b", None),                     # MQA
+    ("starcoder2_3b", dict(kv_dtype="int8")),  # int8 K/V + scales
+    ("recurrentgemma_9b", dict(num_layers=7)),  # local_attn + rglru, tail
+    ("mamba2_370m", None),
+    ("qwen2_5_32b", "attn_tail"),              # a 4-D arena in the tail
+], ids=["starcoder2", "granite-mqa", "int8-kv", "recurrentgemma",
+        "mamba2", "attn-tail"])
+def test_decode_in_place_matches_per_layer_loop(arch, change, mesh):
+    """The served step, which carries the stacked state through the
+    layer loop and indexes it in place, gives bit for bit the tokens,
+    logits, positions and state of a per-layer loop over each unit's own
+    arena, across two page boundaries."""
+    # float32: in bf16 XLA may keep a fused intermediate in float32
+    # (excess precision), so two programs with the same arithmetic but
+    # different fusions can round differently
+    cfg = dataclasses.replace(get_smoke_config(arch), page_size=4,
+                              dtype=jnp.float32)
+    if change == "attn_tail":
+        cfg = _with_attn_tail(cfg)
+    elif change:
+        cfg = dataclasses.replace(cfg, **change)
+    assert cfg.full_units >= 2
+    params = T.init_params(cfg, jax.random.PRNGKey(2))
+    steps = 10                                 # positions 0-9: pages 0-2
+    toks = jax.random.randint(jax.random.PRNGKey(3), (2, steps), 0,
+                              cfg.vocab_size)
+    got = _served_decode(cfg, mesh, params, toks, steps)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(dec, "_apply_units", _units_by_layer)
+        want = _served_decode(cfg, mesh, params, toks, steps)
+    got_leaves, got_tree = jax.tree.flatten(got)
+    want_leaves, want_tree = jax.tree.flatten(want)
+    assert got_tree == want_tree
+    for g, w in zip(got_leaves, want_leaves):
+        assert g.dtype == w.dtype and np.array_equal(g, w)
+    _, ds = got
+    assert cfg.attn_layers == 0 or (ds["kv_pos"] >= 0).sum() == 2 * steps
+
+
 def test_engine_generate_evict_recover(mesh):
     cfg = dataclasses.replace(get_smoke_config("qwen2_5_32b"), page_size=8)
     params = T.init_params(cfg, jax.random.PRNGKey(0))

@@ -11,7 +11,9 @@ process at a time may load the TPU library, and every test worker
 imports this file.  Keep these tests in this one file.
 """
 
+import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -76,13 +78,14 @@ def _on(sharding, tree):
         tree)
 
 
-def test_decode_step_starcoder2_3b_fits_one_v5e(mesh, starcoder):
-    cfg = starcoder
-    acfg = arena_config(cfg, LANES, MAX_SEQ)
+def _compile_decode_step(mesh, cfg, lanes, max_seq):
+    """The served decode step compiled for the described chip, with the
+    parameter and decode-state shapes it was compiled for."""
+    acfg = arena_config(cfg, lanes, max_seq)
     pshape = jax.eval_shape(
         functools.partial(T.init_params, cfg, jax.random.PRNGKey(0)))
     dshape = jax.eval_shape(functools.partial(
-        dec.make_dstate, cfg, batch=LANES, max_seq=MAX_SEQ,
+        dec.make_dstate, cfg, batch=lanes, max_seq=max_seq,
         pages_per_shard=acfg.total_words + 1))
     step, pspecs, sspecs = dec.make_decode_step(cfg, mesh, pshape)
 
@@ -92,15 +95,48 @@ def test_decode_step_starcoder2_3b_fits_one_v5e(mesh, starcoder):
                 a.shape, a.dtype, sharding=NamedSharding(mesh, s)),
             shapes, specs)
 
-    tokens = jax.ShapeDtypeStruct((LANES,), jnp.int32,
+    tokens = jax.ShapeDtypeStruct((lanes,), jnp.int32,
                                   sharding=NamedSharding(mesh, P("data")))
     compiled = step.lower(place(pshape, pspecs), place(dshape, sspecs),
                           tokens).compile()
+    return compiled, pshape, dshape
+
+
+def test_decode_step_starcoder2_3b_fits_one_v5e(mesh, starcoder):
+    compiled, pshape, _ = _compile_decode_step(mesh, starcoder, LANES,
+                                               MAX_SEQ)
     ma = compiled.memory_analysis()
     param_bytes = sum(a.size * a.dtype.itemsize
                       for a in jax.tree.leaves(pshape))
     assert param_bytes > 6e9                       # bf16 at published widths
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+_MiB = 1 << 20
+
+
+@pytest.mark.parametrize("arch,num_layers,max_seq,temp_limit", [
+    ("starcoder2_3b", None, 4096, 64 * _MiB),
+    # one 13-layer pipeline stage; 72 MiB of its temporaries is one
+    # [6144, 6144] weight re-laid out in the layer loop, not K/V
+    ("granite_20b", 13, 8192, 96 * _MiB),
+], ids=["starcoder2-3b", "granite-20b-13l"])
+def test_decode_step_updates_the_stacked_arena_in_place(
+        mesh, arch, num_layers, max_seq, temp_limit):
+    """The stacked K/V arena is carried through the layer loop and
+    written where it lies: no temporary buffer holds a copy of it, and
+    no op copies or rewrites it whole (16 lanes, the cells' sizes)."""
+    cfg = get_config(arch)
+    if num_layers:
+        cfg = dataclasses.replace(cfg, num_layers=num_layers)
+    compiled, _, dshape = _compile_decode_step(mesh, cfg, 16, max_seq)
+    arena = dshape["units"]["l0"]["k"]
+    assert arena.shape[0] == cfg.full_units
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
+    whole = ",".join(map(str, arena.shape))
+    moves = re.findall(rf"= \w+\[{whole}\]\S* (copy|dynamic-update-slice)\(",
+                       compiled.as_text())
+    assert not moves, moves
 
 
 @pytest.mark.parametrize("program", ["alloc", "free", "alloc_large",
