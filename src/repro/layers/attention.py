@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from .common import param
-from .rope import apply_rope
+from .rope import apply_rope, layer_rope
 
 NEG_INF = -1e30
 
@@ -35,7 +35,7 @@ def init_attention(cfg, key):
     return p
 
 
-def _qkv(cfg, p, x, positions):
+def _qkv(cfg, p, x, positions, window: int = 0):
     B, S, _ = x.shape
     h, k, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = jnp.einsum("bsd,de->bse", x, p["wq"])
@@ -47,8 +47,9 @@ def _qkv(cfg, p, x, positions):
     kk = kk.reshape(B, S, k, dh)
     v = v.reshape(B, S, k, dh)
     if cfg.use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        kk = apply_rope(kk, positions, cfg.rope_theta)
+        rope = layer_rope(cfg, local=window > 0)
+        q = apply_rope(q, positions, **rope)
+        kk = apply_rope(kk, positions, **rope)
     return q, kk, v
 
 
@@ -58,7 +59,7 @@ def full_attention(cfg, p, x, positions, *, causal: bool = True,
     B, S, _ = x.shape
     h, kvh, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     g = h // kvh
-    q, k, v = _qkv(cfg, p, x, positions)
+    q, k, v = _qkv(cfg, p, x, positions, window)
     qg = q.reshape(B, S, kvh, g, dh)
     scores = jnp.einsum("bskgd,btkd->bkgst", qg, k,
                         preferred_element_type=jnp.float32)
@@ -90,7 +91,7 @@ def chunked_attention(cfg, p, x, positions, *, causal: bool = True,
     B, S, _ = x.shape
     h, kvh, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     g = h // kvh
-    q, k, v = _qkv(cfg, p, x, positions)
+    q, k, v = _qkv(cfg, p, x, positions, window)
     C = min(kv_chunk, S)
     while S % C:
         C -= 1
@@ -143,7 +144,7 @@ def pallas_attention(cfg, p, x, positions, *, causal: bool = True,
     from ..runtime import pallas_interpret
     B, S, _ = x.shape
     h, kvh, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q, k, v = _qkv(cfg, p, x, positions)
+    q, k, v = _qkv(cfg, p, x, positions, window)
     out = flash_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                           v.transpose(0, 2, 1, 3), causal=causal,
                           window=window, interpret=pallas_interpret())

@@ -17,23 +17,32 @@ from .common import param
 
 
 def init_moe(cfg, key):
+    """The router spans every routed expert; ``num_experts`` (the experts
+    held here) each get their own matrices, scaled by their fan-in."""
     kr, k1, k2, k3 = jax.random.split(key, 4)
     d, f, e = cfg.d_model, cfg.d_ff, cfg.num_experts
     ep = e + cfg.expert_pad          # zero-padded so E divides the TP axis
     p = {
-        "router": param(kr, (d, e), jnp.float32),
-        "wi": param(k1, (ep, d, f), cfg.dtype),
-        "wo": param(k3, (ep, f, d), cfg.dtype),
+        "router": param(kr, (d, cfg.routed_experts), jnp.float32),
+        "wi": param(k1, (ep, d, f), cfg.dtype, scale=d ** -0.5),
+        "wo": param(k3, (ep, f, d), cfg.dtype, scale=f ** -0.5),
     }
     if cfg.mlp == "swiglu":
-        p["wg"] = param(k2, (ep, d, f), cfg.dtype)
+        p["wg"] = param(k2, (ep, d, f), cfg.dtype, scale=d ** -0.5)
     return p
 
 
 def apply_moe(cfg, p, x, *, capacity_factor: float = 1.25,
               constrain=lambda a: a):
     """Dispatch router: 'local' (per-batch-row, shard-friendly) or the
-    original 'global' argsort dispatch."""
+    original 'global' argsort dispatch.  Both compute a whole expert
+    set; a held share of the routed experts is served by
+    ``serving.tp_layers.moe_decode_tp``."""
+    if cfg.routed_experts != cfg.num_experts:
+        raise NotImplementedError(
+            f"{cfg.name}: the full-sequence MoE path computes all routed "
+            f"experts, and this configuration holds {cfg.num_experts} of "
+            f"{cfg.routed_experts}")
     if getattr(cfg, "moe_dispatch", "global") == "local":
         return apply_moe_local(cfg, p, x, capacity_factor=capacity_factor,
                                constrain=constrain)

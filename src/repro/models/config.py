@@ -35,6 +35,13 @@ class ModelConfig:
     qkv_bias: bool = False
     use_rope: bool = True
     rope_theta: float = 1e4
+    # YaRN on the full-attention layers (arXiv:2309.00071; 0 = plain
+    # RoPE); window layers keep plain RoPE
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.0
     causal: bool = True
     window: int = 0                # local-attention window (0 = full)
     # ffn options
@@ -42,7 +49,9 @@ class ModelConfig:
     norm: str = "rmsnorm"
     tie_embeddings: bool = False
     # moe
-    num_experts: int = 0
+    num_experts: int = 0           # experts held here
+    router_experts: int = 0        # experts routed over (0 = num_experts)
+    expert_first: int = 0          # id of the first expert held here
     top_k: int = 0
     expert_pad: int = 0            # zero experts padding E to a TP multiple
     capacity_factor: float = 1.25
@@ -86,6 +95,24 @@ class ModelConfig:
                    if mx in ("attn", "local_attn"))
 
     @property
+    def routed_experts(self) -> int:
+        return self.router_experts or self.num_experts
+
+    @property
+    def has_full_attn(self) -> bool:
+        return any(mx == "attn" for mx, _ in self.layer_specs)
+
+    @property
+    def ring_pages(self) -> int:
+        """Ring slots a lane's window layers keep (0: no window layer):
+        every position inside the window lies on one of them."""
+        if not any(mx == "local_attn" for mx, _ in self.layer_specs):
+            return 0
+        if self.window <= 0:
+            raise ValueError("local_attn layers need a window > 0")
+        return -(-self.window // self.page_size) + 1
+
+    @property
     def is_encoder(self) -> bool:
         return not self.causal
 
@@ -113,7 +140,7 @@ class ModelConfig:
             elif ffn == "moe":
                 k = 3 if self.mlp == "swiglu" else 2
                 n += self.num_experts * k * self.d_model * self.d_ff
-                n += self.d_model * self.num_experts
+                n += self.d_model * self.routed_experts
         return n
 
     def active_param_count(self) -> int:

@@ -8,8 +8,14 @@ the new token's K/V into the stacked arena [U, pages, page, K, dh] at
 ``[unit, page, slot]`` and gathers ``[unit, block_table]`` in place, and
 a recurrent mixer reads its unit's slice of its small per-batch state
 and writes it back, so neither a layer nor the step copies the arena.
-Named scopes (``attention``, with ``kv_write`` and ``kv_gather`` inside
-it, ``ffn``, ``logits``) label the compiled ops in a profile.  Batch
+Full-attention layers index their arena through ``block_table`` by
+page number; window layers (``local_attn``) through ``ring_table``, a
+ring of ``cfg.ring_pages`` slots a lane whose arena is its own, so the
+page that leaves the window is the one overwritten next.
+Named scopes (``attention`` and, for window layers,
+``window_attention``, with ``kv_write`` and ``kv_gather`` inside them,
+``ffn`` with ``moe`` holding ``router`` and ``experts``, ``logits``)
+label the compiled ops in a profile.  Batch
 and KV pages are sharded over the data axes (shard-local page ids — one
 arena per data shard); ``model`` carries Megatron-style TP plus
 slot-sharded paged attention.
@@ -18,6 +24,9 @@ The decode state is a pytree:
 
   {"pos": i32[B], "block_table": i32[B, P], "kv_pos": i32[B, P, page],
    "units": {"l<i>": mixer-state stacked over units}, "tail": {...}}
+
+and, with window layers, "ring_table": i32[B, R] and "ring_kv_pos":
+i32[B, R, page].
 """
 
 from __future__ import annotations
@@ -31,10 +40,13 @@ from jax.sharding import PartitionSpec as P
 
 from ..layers.common import apply_norm
 from ..models.config import ModelConfig
-from ..runtime import axis_size, shard_map
+from ..runtime import shard_map
 from . import tp_layers as tpl
 
 MODEL_AXIS = "model"
+# each attention kind's page table and slot positions in the decode state
+TABLES = {"attn": ("block_table", "kv_pos"),
+          "local_attn": ("ring_table", "ring_kv_pos")}
 
 
 def data_axes(mesh) -> tuple[str, ...]:
@@ -150,6 +162,8 @@ def dstate_specs(cfg: ModelConfig, mesh, batch_sharded: bool = True):
         "kv_pos": kvp_s,
         "units": mixer_state_specs(cfg, mesh, True, batch_sharded),
     }
+    if cfg.ring_pages:
+        specs["ring_table"], specs["ring_kv_pos"] = bt_s, kvp_s
     tail = {}
     for i, (mixer, _) in enumerate(cfg.tail_specs):
         sub = mixer_state_specs(cfg, mesh, False, batch_sharded)
@@ -164,26 +178,35 @@ def dstate_specs(cfg: ModelConfig, mesh, batch_sharded: bool = True):
 # ---------------------------------------------------------------------------
 def make_dstate(cfg: ModelConfig, *, batch: int, max_seq: int,
                 pages_per_shard: int | None = None, dp_shards: int = 1,
-                dtype=None):
-    """Zero-initialized decode state (host-side; engine fills block tables)."""
+                dtype=None, ring_pages_per_shard: int | None = None):
+    """Zero-initialized decode state (host-side; engine fills block tables).
+
+    Full-attention layers hold ``max_seq / page`` table slots a lane and
+    ``pages_per_shard`` arena pages; window layers a ring of
+    ``cfg.ring_pages`` slots and ``ring_pages_per_shard`` pages.  Both
+    counts default to every lane's slots backed, plus the dump page."""
     from ..layers import rglru, ssd
     dtype = dtype or cfg.dtype
     page = cfg.page_size
-    if cfg.attn_layers == 0:
-        Pn = dp_shards                    # attention-free: vestigial table
-    else:
-        Pn = max(1, max_seq // page)
-        if cfg.window:                    # ring buffer of window pages
-            Pn = min(Pn, (cfg.window + page - 1) // page + 1)
-        Pn = -(-Pn // dp_shards) * dp_shards   # divisible for seq-parallel
-    pages = pages_per_shard or max(batch // dp_shards, 1) * (Pn // dp_shards
-                                   if batch < dp_shards else Pn) + 1
-    pages_g = pages * dp_shards
 
-    def attn_state(n_units):
+    def width(n):                         # divisible for seq-parallel
+        return -(-n // dp_shards) * dp_shards
+
+    def arena_pages(Pn, given):
+        return given or max(batch // dp_shards, 1) * (
+            Pn // dp_shards if batch < dp_shards else Pn) + 1
+
+    # no full-attention layer: a vestigial table
+    Pn = width(max(1, max_seq // page)) if cfg.has_full_attn else dp_shards
+    R = width(cfg.ring_pages) if cfg.ring_pages else 0
+    pages_g = arena_pages(Pn, pages_per_shard) * dp_shards
+    ring_g = arena_pages(R, ring_pages_per_shard) * dp_shards
+
+    def attn_state(n_units, mixer):
         K, dh = cfg.num_kv_heads, cfg.head_dim
-        shape = (pages_g, page, K, dh)
-        sshape = (pages_g, page, K)
+        n = ring_g if mixer == "local_attn" else pages_g
+        shape = (n, page, K, dh)
+        sshape = (n, page, K)
         if n_units:
             shape = (n_units,) + shape
             sshape = (n_units,) + sshape
@@ -198,7 +221,7 @@ def make_dstate(cfg: ModelConfig, *, batch: int, max_seq: int,
     U = cfg.full_units
     for i, (mixer, _) in enumerate(cfg.pattern):
         if mixer in ("attn", "local_attn"):
-            units[f"l{i}"] = attn_state(U)
+            units[f"l{i}"] = attn_state(U, mixer)
         elif mixer == "mamba2":
             s = ssd.mamba2_init_state(cfg, batch)
             units[f"l{i}"] = jax.tree.map(
@@ -209,41 +232,53 @@ def make_dstate(cfg: ModelConfig, *, batch: int, max_seq: int,
                 lambda a: jnp.broadcast_to(a, (U,) + a.shape), s)
     for i, (mixer, _) in enumerate(cfg.tail_specs):
         if mixer in ("attn", "local_attn"):
-            tail[f"t{i}"] = attn_state(0)
+            tail[f"t{i}"] = attn_state(0, mixer)
         elif mixer == "mamba2":
             tail[f"t{i}"] = ssd.mamba2_init_state(cfg, batch)
         elif mixer == "rglru":
             tail[f"t{i}"] = rglru.rglru_init_state(cfg, batch)
-    return {
+    out = {
         "pos": jnp.zeros((batch,), jnp.int32),
         "block_table": jnp.full((batch, Pn), -1, jnp.int32),
         "kv_pos": jnp.full((batch, Pn, page), -1, jnp.int32),
         "units": units,
         "tail": tail,
     }
+    if R:
+        out["ring_table"] = jnp.full((batch, R), -1, jnp.int32)
+        out["ring_kv_pos"] = jnp.full((batch, R, page), -1, jnp.int32)
+    return out
 
 
 # ---------------------------------------------------------------------------
 # the step itself
 # ---------------------------------------------------------------------------
-def _apply_layer_tp(cfg, spec, p, x, pos, block_table, kv_pos, state,
-                    seq_dp_axes=(), unit=None):
-    """One (mixer, ffn) layer.  With ``unit`` the state is stacked over
-    pattern units: attention indexes the stacked arena in place, a
-    recurrent mixer reads and writes back its unit's slice."""
+def _tables(dstate) -> dict:
+    """Each attention kind's (page table, slot positions) in ``dstate``."""
+    return {kind: (dstate[bt], dstate[kvp])
+            for kind, (bt, kvp) in TABLES.items() if bt in dstate}
+
+
+def _apply_layer_tp(cfg, spec, p, x, pos, tables, state, seq_dp_axes=(),
+                    unit=None):
+    """One (mixer, ffn) layer; ``tables`` as ``_tables`` gives them.
+    With ``unit`` the state is stacked over pattern units: attention
+    indexes the stacked arena in place, a recurrent mixer reads and
+    writes back its unit's slice."""
     mixer, ffn = spec
     M = MODEL_AXIS
     h = apply_norm(cfg.norm, p["norm1"], x)
     new_state = state
     if mixer in ("attn", "local_attn"):
-        win = cfg.window if mixer == "local_attn" else 0
+        local = mixer == "local_attn"
+        block_table, kv_pos = tables[mixer]
         scales = ((state["ks"], state["vs"])
                   if cfg.kv_dtype == "int8" else None)
-        with jax.named_scope("attention"):
+        with jax.named_scope("window_attention" if local else "attention"):
             y, ak, av, kv_pos2, nsc = tpl.attn_decode_tp(
                 cfg, p["attn"], h, pos, state["k"], state["v"], block_table,
-                kv_pos, window=win, axis=M, seq_dp_axes=seq_dp_axes,
-                scales=scales, unit=unit)
+                kv_pos, window=cfg.window if local else 0, axis=M,
+                seq_dp_axes=seq_dp_axes, scales=scales, unit=unit)
         new_state = {"k": ak, "v": av}
         if nsc is not None:
             new_state["ks"], new_state["vs"] = nsc
@@ -271,8 +306,7 @@ def _apply_layer_tp(cfg, spec, p, x, pos, block_table, kv_pos, state,
     return x, new_state
 
 
-def _apply_units(cfg, seq_dp_axes, units_p, units_s, x, pos, block_table,
-                 kv_pos):
+def _apply_units(cfg, seq_dp_axes, units_p, units_s, x, pos, tables):
     """The layer loop over pattern units.  The unit-stacked state rides in
     ``lax.scan``'s carry, so every layer updates it in place: no layer
     slices its arena out and no step copies the arena."""
@@ -284,8 +318,8 @@ def _apply_units(cfg, seq_dp_axes, units_p, units_s, x, pos, block_table,
         for i, spec in enumerate(cfg.pattern):
             key = f"l{i}"
             x, units_s[key] = _apply_layer_tp(
-                cfg, spec, unit_p[key], x, pos, block_table, kv_pos,
-                units_s[key], seq_dp_axes, unit=u)
+                cfg, spec, unit_p[key], x, pos, tables, units_s[key],
+                seq_dp_axes, unit=u)
         return (x, units_s), None
 
     (x, units_s), _ = lax.scan(
@@ -298,16 +332,15 @@ def _decode_local(cfg: ModelConfig, seq_dp_axes, params, dstate, tokens,
     """Runs per-device inside shard_map."""
     M = MODEL_AXIS
     pos = dstate["pos"]
-    block_table = dstate["block_table"]
-    kv_pos = dstate["kv_pos"]
+    tables = _tables(dstate)
     x = tpl.embed_tp(params["embed"], tokens, M, sharded=vocab_sharded)
     x, new_units = _apply_units(cfg, seq_dp_axes, params["units"],
-                                dstate["units"], x, pos, block_table, kv_pos)
+                                dstate["units"], x, pos, tables)
     new_tail = {}
     for i, spec in enumerate(cfg.tail_specs):
         st = dstate["tail"].get(f"t{i}")
         x, ns = _apply_layer_tp(cfg, spec, params["tail"][f"t{i}"], x, pos,
-                                block_table, kv_pos, st, seq_dp_axes)
+                                tables, st, seq_dp_axes)
         if ns is not None:
             new_tail[f"t{i}"] = ns
     x = apply_norm(cfg.norm, params["final_norm"], x)
@@ -318,24 +351,19 @@ def _decode_local(cfg: ModelConfig, seq_dp_axes, params, dstate, tokens,
                                         sharded=vocab_sharded)
 
     # one new token is now resident at position pos for every sequence:
-    # advance position; mark its slot in kv_pos (idempotent w.r.t. layers)
-    page_loc = kv_pos.shape[-1]
-    page = page_loc * axis_size(M)
-    P_loc = kv_pos.shape[1]
-    slot = pos % page
-    mine = (slot // page_loc) == lax.axis_index(M)
-    gpage = pos // page
-    if seq_dp_axes:
-        mine = mine & ((gpage // P_loc) == tpl.dp_linear_index(seq_dp_axes))
-        lpage = gpage % P_loc
-    else:
-        lpage = gpage
+    # advance position; mark its slot in each table's kv_pos (idempotent
+    # w.r.t. layers)
     b_ix = jnp.arange(pos.shape[0])
-    lslot = jnp.where(mine, slot % page_loc, 0)
-    kv_pos = kv_pos.at[b_ix, lpage, lslot].set(
-        jnp.where(mine, pos, kv_pos[b_ix, lpage, lslot]))
-    out_state = dict(dstate, pos=pos + 1, kv_pos=kv_pos,
-                     units=new_units, tail=new_tail)
+    out_state = dict(dstate, pos=pos + 1, units=new_units, tail=new_tail)
+    for kind, (_, kvp_key) in TABLES.items():
+        if kind not in tables:
+            continue
+        kv_pos = tables[kind][1]
+        mine, lpage, lslot = tpl.table_slot(
+            pos, kv_pos.shape[1], kv_pos.shape[-1], M, seq_dp_axes,
+            ring=kind == "local_attn")
+        out_state[kvp_key] = kv_pos.at[b_ix, lpage, lslot].set(
+            jnp.where(mine, pos, kv_pos[b_ix, lpage, lslot]))
     if return_logits:
         full = (lax.all_gather(logits_loc, MODEL_AXIS, axis=1, tiled=True)
                 if vocab_sharded else logits_loc)

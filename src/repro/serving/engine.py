@@ -16,6 +16,14 @@ a fresh page from the allocator (vectorized ``alloc`` over all lanes —
 the rank-indexed cache makes the common step allocation-free).  Evicted
 sessions free their pages in one vectorized ``free``.
 
+Window layers (``local_attn``) keep their K/V in a second pool of the
+same allocator (``window_arena_config``): each lane backs its
+``cfg.ring_pages`` ring slots lazily, one page as it reaches each, keeps
+them while it lives (the decode step overwrites the page that leaves
+the window) and frees them at ``finish``.  Occupancy checks and crash
+recovery cover both pools.  Prefix sharing needs a window of K/V the
+ring does not keep, so configurations with window layers refuse it.
+
 Group-commit publish: span-path publications split into a transient half
 (``queue_publish`` — cache entry + prefix lease, effective immediately)
 and a durable half parked in ``_publish_queue``; ``flush_publishes``
@@ -51,7 +59,7 @@ from .prefix_trie_cache import CacheNode, PrefixTrieCache
 from .scheduler import EngineBusy, PendingPublish
 
 __all__ = ["ServingEngine", "Session", "EngineBusy", "PAGE_CLS",
-           "arena_config"]
+           "arena_config", "window_arena_config"]
 
 PAGE_CLS = 0
 
@@ -66,6 +74,7 @@ _OBS_PUB_BATCH = obs.histogram("engine.publish_batch_size")
 # Span names: the host-side phases of a step and of a request's life
 _SP_STEP = "engine.step"
 _SP_PAGE_ALLOC = "engine.page_alloc"   # lane pages: alloc + table upload
+_SP_WINDOW_ALLOC = "engine.window_page_alloc"   # the same, ring pool
 _SP_DISPATCH = "engine.dispatch"       # tokens upload + the step program
 _SP_EMIT = "engine.emit"               # per-lane loop after the tokens
 _SP_FINISH = "engine.finish"
@@ -75,6 +84,8 @@ _D2H_POS = "engine.d2h.pos"
 _D2H_TOKENS = "engine.d2h.tokens"
 _D2H_BT = "engine.d2h.block_table"
 _D2H_BT_ROW = "engine.d2h.bt_row"
+_D2H_RING = "engine.d2h.ring_table"
+_D2H_RING_ROW = "engine.d2h.ring_row"
 _D2H_KV_POS = "engine.d2h.kv_pos"
 _D2H_OFFS = "engine.d2h.offs"
 _D2H_SPAN_OFF = "engine.d2h.span_off"
@@ -108,6 +119,39 @@ def arena_config(cfg: ModelConfig, lanes: int, max_seq: int,
                           cache_cap=max(64, 2 * lanes))
 
 
+def window_arena_config(cfg: ModelConfig, lanes: int,
+                        pages_per_sb: int = 16) -> ja.ArenaConfig | None:
+    """The ring pool window layers allocate from (None without window
+    layers): every lane's ring slots, rounded up to whole superblocks,
+    plus one superblock; the decode state holds ``total_words + 1``
+    ring pages, the last one the dump page."""
+    if not cfg.ring_pages:
+        return None
+    return ja.ArenaConfig(
+        num_sbs=-(-lanes * cfg.ring_pages // pages_per_sb) + 1,
+        sb_words=pages_per_sb, class_words=(1,),
+        cache_cap=max(64, 2 * lanes, cfg.ring_pages))
+
+
+def _ref_rows(bt: np.ndarray, lanes) -> dict[int, list[int]]:
+    """Filter function of a page table: each listed lane's root block
+    (its first page) references the lane's other pages."""
+    rows: dict[int, list[int]] = {}
+    for lane in lanes:
+        pages = bt[lane][bt[lane] >= 0]
+        if pages.size:
+            rows.setdefault(int(pages[0]), []).extend(pages[1:].tolist())
+    return rows
+
+
+def _ref_array(n_slots: int, width: int, rows: dict) -> np.ndarray:
+    refs = np.full((n_slots, max([width] + [len(v) for v in rows.values()])),
+                   -1, np.int32)
+    for root, tgts in rows.items():
+        refs[root, :len(tgts)] = tgts
+    return refs
+
+
 class ServingEngine:
     def __init__(self, cfg: ModelConfig, mesh, params, *, lanes: int = 8,
                  max_seq: int = 512, pages_per_sb: int = 16,
@@ -137,13 +181,24 @@ class ServingEngine:
                                                        cfg=self.acfg))
         self._trim_large = jax.jit(functools.partial(ja.trim_large,
                                                      cfg=self.acfg))
+        # the window layers' ring pool: its own arena and allocator
+        # state, one root per lane (recovery roots each lane's ring)
+        self.wacfg = window_arena_config(cfg, lanes, pages_per_sb)
+        ring_pages = None
+        if self.wacfg is not None:
+            self.wastate = ja.init_state(self.wacfg, max_roots=lanes)
+            self._walloc = jax.jit(functools.partial(
+                ja.alloc, cfg=self.wacfg, cls=PAGE_CLS))
+            self._wfree = jax.jit(functools.partial(
+                ja.free, cfg=self.wacfg, cls=PAGE_CLS))
+            ring_pages = self.wacfg.total_words + 1
         self.lane_states = LaneStates(lanes)
         pshape = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)
         self.step_fn, _, _ = dec.make_decode_step(cfg, mesh, pshape)
         self.dstate = dec.make_dstate(cfg, batch=lanes, max_seq=max_seq,
                                       pages_per_shard=self.acfg.total_words
-                                      + 1)
+                                      + 1, ring_pages_per_shard=ring_pages)
         # prefix sharing (RadixAttention-style) — the trie cache keeps
         # the flat exact-match dict API (entries / tokens / page_refs /
         # lookup) and adds longest-prefix-match over published prompts:
@@ -205,8 +260,17 @@ class ServingEngine:
         self.prefix_cache.page_refs = refs
 
     # ------------------------------------------------------------- requests
+    def _refuse_sharing(self) -> None:
+        if self.wacfg is not None:
+            raise ValueError(
+                f"{self.cfg.name}: prefix sharing is not supported with "
+                f"window layers (a hit would need the ring's last window "
+                f"of K/V, which the ring does not publish)")
+
     def add_request(self, prompt: list[int],
                     share_prefix: bool = False) -> int:
+        if share_prefix:
+            self._refuse_sharing()
         with obs.span(_SP_ADD):
             lane = self.lane_states.acquire()
             if lane is None:
@@ -237,7 +301,7 @@ class ServingEngine:
             # allocated pages.  A mid-edge match first materializes the
             # boundary as a trie split (durable when the node has a record).
             pnode, pk = None, 0
-            if share_prefix and hit is None and self.cfg.attn_layers > 0:
+            if share_prefix and hit is None and self.cfg.has_full_attn:
                 pnode, pk = self.prefix_cache.match_partial(prompt)
                 if (pnode is not None
                         and pk * self.cfg.page_size >= len(prompt)):
@@ -253,7 +317,7 @@ class ServingEngine:
                         pnode = m
                 if pnode is None:
                     pk = 0
-            if (self.cfg.attn_layers > 0 and hit is None and pnode is None
+            if (self.cfg.has_full_attn and hit is None and pnode is None
                     and n_prompt_pages > self.acfg.sb_words):
                 n_ahead = min(-(-self.max_seq // self.cfg.page_size),
                               table_width)
@@ -480,6 +544,7 @@ class ServingEngine:
         parks in the group-commit queue until ``flush_publishes``.
         Page-path entries are transient-only and complete here.  Returns
         True when a new entry was created."""
+        self._refuse_sharing()
         s = self.sessions[lane]
         pos = int(_d2h(_D2H_POS, self.dstate["pos"][lane]))
         page = self.cfg.page_size
@@ -700,7 +765,8 @@ class ServingEngine:
             # the slot is already backed (prefix hit or a reserved large span)
             pos = _d2h(_D2H_POS, self.dstate["pos"])
             page = self.cfg.page_size
-            need = active & (pos % page == 0) & (self.cfg.attn_layers > 0)
+            boundary = active & (pos % page == 0)
+            need = boundary & self.cfg.has_full_attn
             if need.any():
                 # only boundary steps pay the block-table device→host sync
                 bt_now = _d2h(_D2H_BT, self.dstate["block_table"])
@@ -717,6 +783,8 @@ class ServingEngine:
                             raise MemoryError("KV arena exhausted")
                         bt[lane, pos[lane] // page] = offs[lane]
                     self.dstate["block_table"] = jnp.asarray(bt)
+            if self.wacfg is not None:
+                self._back_ring_slots(boundary, pos)
 
             with obs.span(_SP_DISPATCH):
                 self.dstate, toks = self.step_fn(self.params, self.dstate,
@@ -739,7 +807,40 @@ class ServingEngine:
                     if len(s.tokens) >= self.max_seq - 1:
                         self.finish(lane)
             sp.args.update(prefill_lanes=n_prefill, decode_lanes=len(out))
+            if self.wacfg is not None:
+                # lanes past the window, and the K/V tokens window layers
+                # attend (each lane's position + 1, at most the window)
+                w = self.cfg.window
+                sp.args.update(
+                    window_lanes=int((active & (pos >= w)).sum()),
+                    window_tokens=int(np.minimum(pos[active] + 1, w).sum()))
             return out
+
+    def _back_ring_slots(self, boundary: np.ndarray, pos: np.ndarray) -> None:
+        """Give each lane that has just reached an unbacked ring slot a
+        page of the ring pool; a lane past its first ``ring_pages``
+        pages writes over the slot's page in place."""
+        page, R = self.cfg.page_size, self.cfg.ring_pages
+        need = boundary & (pos // page < R)
+        if not need.any():
+            return
+        with obs.span(_SP_WINDOW_ALLOC):
+            rt = _d2h(_D2H_RING, self.dstate["ring_table"]).copy()
+            slot = (pos // page) % R
+            need &= rt[np.arange(self.lanes), slot] < 0
+            if not need.any():
+                return
+            self.wastate, offs = self._walloc(state=self.wastate,
+                                              need=jnp.asarray(need))
+            offs = _d2h(_D2H_OFFS, offs)
+            for lane in np.nonzero(need)[0]:
+                if offs[lane] < 0:
+                    raise MemoryError("window page pool exhausted")
+                rt[lane, slot[lane]] = offs[lane]
+            # placed as the table it replaces, so the step sees no new
+            # argument layout (and compiles nothing new) for it
+            self.dstate["ring_table"] = jax.device_put(
+                rt, self.dstate["ring_table"].sharding)
 
     def finish(self, lane: int) -> None:
         """Evict a session: free its pages (shared pages only at ref 0,
@@ -798,6 +899,17 @@ class ServingEngine:
                                          mask=jnp.asarray(offs >= 0))
             self.dstate["block_table"] = \
                 self.dstate["block_table"].at[lane].set(-1)
+            if self.wacfg is not None:
+                row = _d2h(_D2H_RING_ROW, self.dstate["ring_table"][lane])
+                ring = row[row >= 0]
+                if ring.size:
+                    offs = np.full((self.wacfg.cache_cap,), -1, np.int32)
+                    offs[:ring.size] = ring
+                    self.wastate = self._wfree(state=self.wastate,
+                                               offs=jnp.asarray(offs),
+                                               mask=jnp.asarray(offs >= 0))
+                self.dstate["ring_table"] = \
+                    self.dstate["ring_table"].at[lane].set(-1)
             self.astate = ja.set_root(self.astate, lane, jnp.int32(-1))
             self.lane_states.release(lane)
 
@@ -832,9 +944,21 @@ class ServingEngine:
                "live_spans": live["large"],
                "lane_spans": len(self.large_spans),
                "span_superblocks": placed, "lane_span_superblocks": span_sbs}
+        ring_ok = True
+        if self.wacfg is not None:
+            rt = np.asarray(self.dstate["ring_table"])
+            ring = [p for lane in self.sessions
+                    for p in rt[lane][rt[lane] >= 0].tolist()]
+            if len(set(ring)) != len(ring):
+                raise AssertionError(
+                    f"a window page is held by two lanes: {ring}")
+            occ["live_window_pages"] = ja.live_blocks(
+                self.wastate, self.wacfg)[PAGE_CLS]
+            occ["lane_window_pages"] = len(ring)
+            ring_ok = occ["live_window_pages"] == len(ring)
         if (occ["live_pages"] != occ["lane_pages"]
                 or occ["live_spans"] != occ["lane_spans"]
-                or placed != span_sbs):
+                or placed != span_sbs or not ring_ok):
             raise AssertionError(f"allocator occupancy != lane holdings: "
                                  f"{occ}")
         return occ
@@ -854,24 +978,34 @@ class ServingEngine:
         pass traces the chain precisely and counts the record→span
         reference like a lane root, which is what keeps a published span
         alive across a crash with no lane rooted on it."""
-        S = jr.num_slots(self.acfg)
-        R = int(self.dstate["block_table"].shape[1])
         bt = _d2h(_D2H_BT, self.dstate["block_table"])
-        rows: dict[int, list[int]] = {}
-        for lane, s in self.sessions.items():
-            if s.done:
-                continue
-            pages = bt[lane][bt[lane] >= 0]
-            if pages.size == 0:
-                continue
-            rows.setdefault(int(pages[0]), []).extend(pages[1:].tolist())
+        rows = _ref_rows(bt, self._live_lanes())
         for rec_off, tgts in self.prefix_store.ref_rows().items():
             rows.setdefault(rec_off, []).extend(tgts)
-        width = max([R] + [len(v) for v in rows.values()])
-        refs = np.full((S, width), -1, np.int32)
-        for root, tgts in rows.items():
-            refs[root, :len(tgts)] = tgts
-        return refs
+        return _ref_array(jr.num_slots(self.acfg), bt.shape[1], rows)
+
+    def _live_lanes(self) -> list[int]:
+        return [lane for lane, s in self.sessions.items() if not s.done]
+
+    def _recover_window_pool(self) -> int:
+        """The ring pool's crash recovery: its transient state rebuilt
+        by mark-sweep from its persistent fields and the ring tables,
+        each live lane's first ring page its root.  Returns the marked
+        page count."""
+        rt = _d2h(_D2H_RING, self.dstate["ring_table"])
+        lanes = self._live_lanes()
+        roots = np.full((self.lanes,), -1, np.int32)
+        for lane in lanes:
+            ring = rt[lane][rt[lane] >= 0]
+            if ring.size:
+                roots[lane] = ring[0]
+        refs = _ref_array(jr.num_slots(self.wacfg), rt.shape[1],
+                          _ref_rows(rt, lanes))
+        persistent = ja.persistent_snapshot(self.wastate)
+        persistent["roots"] = jnp.asarray(roots)
+        self.wastate, marked = jr.recover(self.wacfg, persistent,
+                                          jnp.asarray(refs))
+        return int(_d2h(_D2H_MARKED, marked).sum())
 
     def crash_and_recover(self) -> dict:
         """Simulate losing all transient allocator state, then rebuild it
@@ -969,6 +1103,12 @@ class ServingEngine:
             n_marked = int(_d2h(_D2H_MARKED, marked).sum())
             sp.add(n_marked)
         _phase(sp)
+        window_marked = None
+        if self.wacfg is not None:
+            with obs.span("engine_recovery.window_mark_sweep") as sp:
+                window_marked = self._recover_window_pool()
+                sp.add(window_marked)
+            _phase(sp)
         # drop + recount the engine's transient sharing records (recovery
         # step 2: caches start empty in a fresh process).  Span-backed
         # pages are excluded: their sharing is the *span's* refcount
@@ -1047,8 +1187,11 @@ class ServingEngine:
                         n_keep=jnp.int32(lease_sbs), n_held=jnp.int32(-1))
                     sp.add(1)
         _phase(sp)
-        return {"marked": n_marked,
-                "live_before": live_before, "live_after": live_after,
-                "index_records": len(survivors),
-                "trie_pruned": trie_pruned,
-                "phases": phases}
+        out = {"marked": n_marked,
+               "live_before": live_before, "live_after": live_after,
+               "index_records": len(survivors),
+               "trie_pruned": trie_pruned,
+               "phases": phases}
+        if window_marked is not None:
+            out["window_marked"] = window_marked
+        return out

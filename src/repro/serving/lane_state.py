@@ -80,4 +80,7 @@ def reset_lane(dstate: dict, lane: int) -> dict:
     dstate["pos"] = dstate["pos"].at[lane].set(0)
     dstate["block_table"] = dstate["block_table"].at[lane].set(-1)
     dstate["kv_pos"] = dstate["kv_pos"].at[lane].set(-1)
+    if "ring_table" in dstate:
+        dstate["ring_table"] = dstate["ring_table"].at[lane].set(-1)
+        dstate["ring_kv_pos"] = dstate["ring_kv_pos"].at[lane].set(-1)
     return dstate

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..layers.rope import apply_rope
+from ..layers.rope import apply_rope, layer_rope
 from ..runtime import axis_size
 
 NEG_INF = -1e30
@@ -90,10 +90,35 @@ def dp_linear_index(dp_axes) -> jax.Array:
     return out
 
 
+def table_slot(pos, P, page_loc, axis, seq_dp_axes=(), ring=False):
+    """Where each lane's token at ``pos`` lives in a page table of local
+    width ``P``: (this shard holds it, its local table column, its local
+    slot in the page).  A ring table wraps: the token's page is column
+    ``pos // page`` modulo the table's global width, so the page that
+    leaves a window layer's window is the one written next."""
+    page = page_loc * _tp(axis)
+    slot = pos % page
+    mine = (slot // page_loc) == _idx(axis)
+    gpage = pos // page                       # global page index of the token
+    if ring:
+        n_dp = 1
+        for a in seq_dp_axes:
+            n_dp *= axis_size(a)
+        gpage = gpage % (P * n_dp)
+    if seq_dp_axes:
+        mine = mine & ((gpage // P) == dp_linear_index(seq_dp_axes))
+        lpage = gpage % P
+    else:
+        lpage = gpage
+    return mine, lpage, jnp.where(mine, slot % page_loc, 0)
+
+
 def attn_decode_tp(cfg, p, x, pos, arena_k, arena_v, block_table, kv_pos,
                    *, window: int = 0, axis: str = "model",
                    seq_dp_axes: tuple = (), scales=None, unit=None):
-    """One-token paged attention.
+    """One-token paged attention.  A window layer (``window`` > 0) reads
+    a ring table (``table_slot``) and plain RoPE; a full layer its
+    position-indexed table and, where the configuration scales it, YaRN.
 
     x:           [B, D] replicated over ``axis``
     arena_k/v:   [U, pages_loc, page_loc, K, dh] when ``unit`` (an i32
@@ -119,9 +144,7 @@ def attn_decode_tp(cfg, p, x, pos, arena_k, arena_v, block_table, kv_pos,
     B, D = x.shape
     h, kvh, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     g = h // kvh
-    tp = _tp(axis)
     page_loc = arena_k.shape[-3]
-    page = page_loc * tp
     P = block_table.shape[1]
     dump = arena_k.shape[-4] - 1
     at = () if unit is None else (unit,)      # leading index into the arena
@@ -140,22 +163,15 @@ def attn_decode_tp(cfg, p, x, pos, arena_k, arena_v, block_table, kv_pos,
     k_new = k_new.reshape(B, kvh, dh)
     v_new = v_new.reshape(B, kvh, dh)
     if cfg.use_rope:
-        q = apply_rope(q[:, None], pos[:, None], cfg.rope_theta)[:, 0]
-        k_new = apply_rope(k_new[:, None], pos[:, None], cfg.rope_theta)[:, 0]
+        rope = layer_rope(cfg, local=window > 0)
+        q = apply_rope(q[:, None], pos[:, None], **rope)[:, 0]
+        k_new = apply_rope(k_new[:, None], pos[:, None], **rope)[:, 0]
 
     # scatter the new token's k/v into the (dp, slot) shard that owns it
-    slot = pos % page
-    mine = (slot // page_loc) == _idx(axis)
-    gpage = pos // page                       # global page index of the token
-    if seq_dp_axes:
-        dpi = dp_linear_index(seq_dp_axes)
-        mine = mine & ((gpage // P) == dpi)
-        lpage = gpage % P
-    else:
-        lpage = gpage
+    mine, lpage, lslot = table_slot(pos, P, page_loc, axis, seq_dp_axes,
+                                    ring=window > 0)
     pid = jnp.take_along_axis(block_table, lpage[:, None], axis=1)[:, 0]
     pid_w = jnp.where(mine & (pid >= 0), pid, dump)
-    lslot = jnp.where(mine, slot % page_loc, 0)
     b_ix = jnp.arange(B)
     with jax.named_scope("kv_write"):
         if scales is not None:
@@ -239,28 +255,45 @@ def mlp_decode_tp(cfg, p, x, axis):
 
 
 def moe_decode_tp(cfg, p, x, axis):
-    """Expert-parallel decode: every local expert runs densely over the
-    (small) token batch; gates mask the combine; one psum merges shards."""
-    B = x.shape[0]
+    """Expert-parallel decode over the experts held here: the router
+    (float32 at HIGHEST precision, at its published width) scores every
+    routed expert; a token's top k are the experts ranked below k by
+    logit (ties to the lower id, as ``lax.top_k`` breaks them), weighted
+    by softmax over those k (equal to softmax over all, then top-k,
+    then renormalised); each held expert (``cfg.expert_first`` onwards,
+    split over ``axis``) runs densely over the (small) token batch, and
+    gates mask the combine, so an expert held elsewhere adds nothing;
+    one psum merges shards."""
     e_loc = p["wi"].shape[0]
-    e_real = p["router"].shape[1]
-    logits = jnp.einsum("bd,de->be", x.astype(jnp.float32), p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate, expert = lax.top_k(probs, cfg.top_k)
-    gate = gate / jnp.clip(gate.sum(-1, keepdims=True), 1e-9)
-    gate_full = jnp.zeros((B, e_real), jnp.float32)
-    gate_full = gate_full.at[jnp.arange(B)[:, None], expert].add(gate)
-    # pad gates out to the padded expert count, slice this shard's experts
-    gate_pad = jnp.pad(gate_full, ((0, 0), (0, e_loc * _tp(axis) - e_real)))
-    gl = lax.dynamic_slice_in_dim(gate_pad, _idx(axis) * e_loc, e_loc, 1)
-    h = jnp.einsum("bd,edf->ebf", x, p["wi"])
-    if cfg.mlp == "swiglu":
-        gg = jnp.einsum("bd,edf->ebf", x, p["wg"])
-        h = jax.nn.silu(gg.astype(jnp.float32)).astype(x.dtype) * h
-    else:
-        h = jax.nn.gelu(h.astype(jnp.float32)).astype(x.dtype)
-    y = jnp.einsum("ebf,efd->ebd", h, p["wo"])
-    y = jnp.einsum("ebd,be->bd", y.astype(jnp.float32), gl)
+    with jax.named_scope("moe"):
+        with jax.named_scope("router"):
+            logits = jnp.einsum("bd,de->be", x.astype(jnp.float32),
+                                p["router"], precision=lax.Precision.HIGHEST)
+            E = logits.shape[-1]
+            ids = jnp.arange(E)
+            above = ((logits[:, None, :] > logits[:, :, None])
+                     | ((logits[:, None, :] == logits[:, :, None])
+                        & (ids[None, :] < ids[:, None])))
+            chosen = above.sum(-1) < cfg.top_k                   # [B, E]
+            w = jnp.where(chosen, jnp.exp(
+                logits - logits.max(-1, keepdims=True)), 0.0)
+            gate = w / w.sum(-1, keepdims=True)
+            # this shard's experts (padding past the held ones gates 0)
+            first = cfg.expert_first + _idx(axis) * e_loc
+            gate = jnp.pad(gate, ((0, 0), (0, max(
+                0, cfg.expert_first + e_loc * _tp(axis) - E))))
+            gl = jnp.where(
+                first + jnp.arange(e_loc) < cfg.expert_first + cfg.num_experts,
+                lax.dynamic_slice_in_dim(gate, first, e_loc, 1), 0.0)
+        with jax.named_scope("experts"):
+            h = jnp.einsum("bd,edf->ebf", x, p["wi"])
+            if cfg.mlp == "swiglu":
+                gg = jnp.einsum("bd,edf->ebf", x, p["wg"])
+                h = jax.nn.silu(gg.astype(jnp.float32)).astype(x.dtype) * h
+            else:
+                h = jax.nn.gelu(h.astype(jnp.float32)).astype(x.dtype)
+            y = jnp.einsum("ebf,efd->ebd", h, p["wo"])
+            y = jnp.einsum("ebd,be->bd", y.astype(jnp.float32), gl)
     return lax.psum(y, axis).astype(x.dtype)
 
 

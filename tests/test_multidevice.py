@@ -93,10 +93,11 @@ _SCRIPT = textwrap.dedent("""
                                           batch_sharded=False,
                                           return_logits=True)
         ds = dec.make_dstate(cfgh, batch=1, max_seq=16, dp_shards=dp)
-        Pn = ds["block_table"].shape[1]
-        # page slot j lives on data shard j // (Pn/dp); ids are shard-local
-        bt = (np.arange(Pn, dtype=np.int32) % (Pn // dp))[None, :]
-        ds["block_table"] = jnp.asarray(bt)
+        # window layers read the ring table: ring slot j lives on data
+        # shard j // (R/dp); ids are shard-local
+        R = ds["ring_table"].shape[1]
+        bt = (np.arange(R, dtype=np.int32) % (R // dp))[None, :]
+        ds["ring_table"] = jnp.asarray(bt)
         outs = []
         for t in range(10):
             ds, tok, lg = step(paramsh, ds, tok1[:, t])

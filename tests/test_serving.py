@@ -19,6 +19,18 @@ def mesh():
     return make_host_mesh()
 
 
+def _fill_tables(ds, order=None):
+    """Back every slot of every page table in ``ds`` (the full-attention
+    table and, with window layers, the ring table) with its own page of
+    that table's arena: in order, or in the order ``order(n)`` gives."""
+    for bt, kind in (("block_table", "attn"), ("ring_table", "local_attn")):
+        if bt in ds:
+            B, Pn = ds[bt].shape
+            ids = np.arange(B * Pn) if order is None else order(B * Pn)
+            ds[bt] = jnp.asarray(ids.astype(np.int32).reshape(B, Pn))
+    return ds
+
+
 def _parity(cfg, mesh, S=24, tol=2e-2):
     key = jax.random.PRNGKey(1)
     params = T.init_params(cfg, key)
@@ -27,10 +39,8 @@ def _parity(cfg, mesh, S=24, tol=2e-2):
     logits_full, _ = T.forward(cfg, params, {"tokens": toks})
     pshape = jax.eval_shape(lambda: params)
     step, _, _ = dec.make_decode_step(cfg, mesh, pshape, return_logits=True)
-    ds = dec.make_dstate(cfg, batch=B, max_seq=64, dp_shards=1)
-    Pn = ds["block_table"].shape[1]
-    ds["block_table"] = jnp.asarray(
-        np.arange(B * Pn, dtype=np.int32).reshape(B, Pn))
+    ds = _fill_tables(dec.make_dstate(cfg, batch=B, max_seq=64,
+                                      dp_shards=1))
     errs = []
     for t in range(S):
         ds, tok, lg = step(params, ds, toks[:, t])
@@ -59,8 +69,7 @@ def test_decode_matches_oracle(arch, fp32, mesh):
     _parity(cfg, mesh, tol=1e-3 if fp32 else 3e-2)
 
 
-def _units_by_layer(cfg, seq_dp_axes, units_p, units_s, x, pos,
-                    block_table, kv_pos):
+def _units_by_layer(cfg, seq_dp_axes, units_p, units_s, x, pos, tables):
     """Reference layer loop: each unit's weights and 4-D arena taken out
     of the stack, every layer applied one by one, the states restacked."""
     per_unit = []
@@ -69,7 +78,7 @@ def _units_by_layer(cfg, seq_dp_axes, units_p, units_s, x, pos,
         unit_s = jax.tree.map(lambda a: a[u], units_s)
         for i, spec in enumerate(cfg.pattern):
             x, unit_s[f"l{i}"] = dec._apply_layer_tp(
-                cfg, spec, unit_p[f"l{i}"], x, pos, block_table, kv_pos,
+                cfg, spec, unit_p[f"l{i}"], x, pos, tables,
                 unit_s[f"l{i}"], seq_dp_axes)
         per_unit.append(unit_s)
     return x, jax.tree.map(lambda *a: jnp.stack(a), *per_unit)
@@ -80,10 +89,8 @@ def _served_decode(cfg, mesh, params, toks, steps):
     scattered over the arena; every output and the final state."""
     pshape = jax.eval_shape(lambda: params)
     step, _, _ = dec.make_decode_step(cfg, mesh, pshape, return_logits=True)
-    ds = dec.make_dstate(cfg, batch=2, max_seq=32, dp_shards=1)
-    Pn = ds["block_table"].shape[1]
-    ds["block_table"] = jnp.asarray(np.random.default_rng(0).permutation(
-        2 * Pn).astype(np.int32).reshape(2, Pn))
+    ds = _fill_tables(dec.make_dstate(cfg, batch=2, max_seq=32, dp_shards=1),
+                      np.random.default_rng(0).permutation)
     outs = []
     for t in range(steps):
         ds, tok, lg = step(params, ds, toks[:, t])
@@ -103,8 +110,9 @@ def _with_attn_tail(cfg):
     ("recurrentgemma_9b", dict(num_layers=7)),  # local_attn + rglru, tail
     ("mamba2_370m", None),
     ("qwen2_5_32b", "attn_tail"),              # a 4-D arena in the tail
+    ("mellum2_12b_a2_5b", dict(window=8)),     # ring + full tables, experts
 ], ids=["starcoder2", "granite-mqa", "int8-kv", "recurrentgemma",
-        "mamba2", "attn-tail"])
+        "mamba2", "attn-tail", "mellum2"])
 def test_decode_in_place_matches_per_layer_loop(arch, change, mesh):
     """The served step, which carries the stacked state through the
     layer loop and indexes it in place, gives bit for bit the tokens,
@@ -134,7 +142,9 @@ def test_decode_in_place_matches_per_layer_loop(arch, change, mesh):
     for g, w in zip(got_leaves, want_leaves):
         assert g.dtype == w.dtype and np.array_equal(g, w)
     _, ds = got
-    assert cfg.attn_layers == 0 or (ds["kv_pos"] >= 0).sum() == 2 * steps
+    # every position is marked in the table its attention layers read
+    kvp = ds["ring_kv_pos"] if cfg.ring_pages else ds["kv_pos"]
+    assert cfg.attn_layers == 0 or (kvp >= 0).sum() == 2 * steps
 
 
 def test_engine_generate_evict_recover(mesh):

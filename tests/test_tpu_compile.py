@@ -23,6 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.configs.mellum2_12b_a2_5b import ep4_config
 from repro.core import jax_alloc as ja
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.kv_update.kernel import kv_update
@@ -30,7 +31,7 @@ from repro.kernels.paged_attention.kernel import paged_attention
 from repro.kernels.ssd_scan.kernel import ssd_scan
 from repro.models import transformer as T
 from repro.serving import decode as dec
-from repro.serving.engine import PAGE_CLS, arena_config
+from repro.serving.engine import PAGE_CLS, arena_config, window_arena_config
 
 V5E_HBM_BYTES = 16e9
 LANES, MAX_SEQ = 8, 4096          # chip_smoke.py's engine
@@ -80,13 +81,16 @@ def _on(sharding, tree):
 
 def _compile_decode_step(mesh, cfg, lanes, max_seq):
     """The served decode step compiled for the described chip, with the
-    parameter and decode-state shapes it was compiled for."""
+    parameter and decode-state shapes it was compiled for (the engine's
+    arenas: both pools where the configuration has window layers)."""
     acfg = arena_config(cfg, lanes, max_seq)
+    wacfg = window_arena_config(cfg, lanes)
     pshape = jax.eval_shape(
         functools.partial(T.init_params, cfg, jax.random.PRNGKey(0)))
     dshape = jax.eval_shape(functools.partial(
         dec.make_dstate, cfg, batch=lanes, max_seq=max_seq,
-        pages_per_shard=acfg.total_words + 1))
+        pages_per_shard=acfg.total_words + 1,
+        ring_pages_per_shard=wacfg and wacfg.total_words + 1))
     step, pspecs, sspecs = dec.make_decode_step(cfg, mesh, pshape)
 
     def place(shapes, specs):
@@ -137,6 +141,51 @@ def test_decode_step_updates_the_stacked_arena_in_place(
     moves = re.findall(rf"= \w+\[{whole}\]\S* (copy|dynamic-update-slice)\(",
                        compiled.as_text())
     assert not moves, moves
+
+
+def test_decode_step_mellum2_ep4_fits_one_v5e(mesh):
+    """Mellum2-12B-A2.5B's one-chip share (16 of 64 experts) at the
+    benchmark's 16 lanes and max_seq 8192: the full layers' arena, the
+    window layers' ring pool and the weights, plus the step's
+    temporaries, fit the chip."""
+    cfg = ep4_config()
+    compiled, pshape, dshape = _compile_decode_step(mesh, cfg, 16, 8192)
+    ma = compiled.memory_analysis()
+    param_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(pshape))
+    assert 7.6e9 < param_bytes < 7.7e9           # 3.83B bf16 parameters
+    # 21 window layers on 9-slot rings, 7 full layers on 64-page tables
+    assert dshape["ring_table"].shape == (16, 9)
+    assert dshape["block_table"].shape == (16, 64)
+    assert dshape["units"]["l0"]["k"].shape[:2] == (7, 161)
+    assert dshape["units"]["l3"]["k"].shape[:2] == (7, 1297)
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+# The parent's compile (before window layers had a pool of their own) of
+# these steps, for a described v5e: argument, output and temporary bytes
+_DENSE_STEP_BYTES = {"starcoder2-3b": (9147515904, 3087017472, 1032192),
+                     "granite-20b-13l": (12170683392, 1105536512, 77084160)}
+
+
+@pytest.mark.parametrize("arch,num_layers,max_seq", [
+    ("starcoder2_3b", None, 4096), ("granite_20b", 13, 8192)],
+    ids=["starcoder2-3b", "granite-20b-13l"])
+def test_dense_decode_step_is_unchanged(mesh, arch, num_layers, max_seq):
+    """A configuration without window layers keeps one pool: the same
+    decode state and the same compiled step, byte for byte in size."""
+    cfg = get_config(arch)
+    if num_layers:
+        cfg = dataclasses.replace(cfg, num_layers=num_layers)
+    compiled, _, dshape = _compile_decode_step(mesh, cfg, 16, max_seq)
+    assert sorted(dshape) == ["block_table", "kv_pos", "pos", "tail",
+                              "units"]
+    assert dshape["block_table"].shape == (16, max_seq // 128)
+    ma = compiled.memory_analysis()
+    got = (ma.argument_size_in_bytes, ma.output_size_in_bytes,
+           ma.temp_size_in_bytes)
+    assert got == _DENSE_STEP_BYTES[
+        "starcoder2-3b" if num_layers is None else "granite-20b-13l"]
 
 
 @pytest.mark.parametrize("program", ["alloc", "free", "alloc_large",
